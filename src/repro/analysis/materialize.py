@@ -23,7 +23,7 @@ def iter_avals(jaxpr) -> Iterator:
     """Yield the output aval of every equation in ``jaxpr``, recursing
     into sub-jaxprs held in equation params (pallas kernel bodies,
     scan/while/cond/jit bodies, custom_vjp branches)."""
-    from jax.core import ClosedJaxpr, Jaxpr
+    from jax.extend.core import ClosedJaxpr, Jaxpr
 
     def subs(val):
         if isinstance(val, (Jaxpr, ClosedJaxpr)):

@@ -216,30 +216,33 @@ def pack_dbb(
     k_dim, n = w.shape
     _check_dims(k_dim, block, nnz)
     kb = k_dim // block
-    blocks = w.reshape(kb, block, n).transpose(0, 2, 1)       # [Kb, N, B]
-    mag = jnp.abs(blocks)
-    _, idx = jax.lax.top_k(mag, nnz)                          # [Kb, N, k]
-    idx = jnp.sort(idx, axis=-1)                              # index-sorted
-    vals = jnp.take_along_axis(blocks, idx, axis=-1)          # [Kb, N, k]
-    # zero-pad slots whose source was already zero keeps blocks canonical
-    vals = jnp.where(jnp.take_along_axis(mag, idx, axis=-1) > 0, vals,
-                     jnp.zeros_like(vals))
-    bitmask = jnp.where(
-        jnp.abs(vals) > 0,
-        (jnp.uint32(1) << idx.astype(jnp.uint32)),
-        jnp.uint32(0),
-    ).sum(axis=-1, dtype=jnp.uint32)                          # [Kb, N]
-    # canonical slot order = bitmask-rank order: live values compact into
-    # the leading slots (dead zero slots trail), which is what the
-    # kernels' popcount-rank decompression assumes. Continuous weights
-    # never produce dead slots mid-block, but quantized (bits=4) input
-    # routinely rounds selected values to exactly zero.
-    live = jnp.abs(vals) > 0
-    order = jnp.argsort(jnp.where(live, idx, idx + block), axis=-1)
-    idx = jnp.take_along_axis(idx, order, axis=-1)
-    vals = jnp.take_along_axis(vals, order, axis=-1)
-    values = vals.transpose(0, 2, 1).reshape(kb * nnz, n)
-    indices = idx.astype(jnp.int32).transpose(0, 2, 1).reshape(kb * nnz, n)
+    # everything stays [Kb, B, N] with N on the lanes: a [.., N, k] layout
+    # would pad its k-wide minor dim to a full 128-lane tile on TPU (32x
+    # the bytes at k = 4), which does not fit HBM for a stacked layer
+    blocks = w.reshape(kb, block, n)                          # [Kb, B, N]
+    sel = dbb_mask(w, block, nnz).reshape(kb, block, n)       # top-k, ties low
+    # canonical slot order = bitmask-rank order: live (non-zero) values
+    # compact into the leading slots in index order, selected zeros
+    # (dead slots) trail — what the kernels' popcount-rank decompression
+    # assumes. Continuous weights never produce dead slots mid-block, but
+    # quantized (bits=4) input routinely rounds selected values to zero.
+    live = sel & (blocks != 0)
+    dead = sel & ~live
+    n_live = live.sum(axis=1, keepdims=True, dtype=jnp.int8)
+    slot = jnp.where(live, jnp.cumsum(live, axis=1, dtype=jnp.int8),
+                     n_live + jnp.cumsum(dead, axis=1, dtype=jnp.int8)) - 1
+    pos = jnp.arange(block, dtype=jnp.int32)[None, :, None]
+    zero = jnp.zeros((), blocks.dtype)
+    vals, idxs = [], []
+    for s in range(nnz):           # one selected position per (block, col)
+        hit = sel & (slot == s)
+        vals.append(jnp.where(hit & live, blocks, zero)
+                    .sum(axis=1, dtype=blocks.dtype))
+        idxs.append(jnp.where(hit, pos, 0).sum(axis=1, dtype=jnp.int32))
+    values = jnp.stack(vals, axis=1).reshape(kb * nnz, n)
+    indices = jnp.stack(idxs, axis=1).reshape(kb * nnz, n)
+    bitmask = jnp.where(live, jnp.uint32(1) << pos.astype(jnp.uint32),
+                        jnp.uint32(0)).sum(axis=1, dtype=jnp.uint32)
     return DbbWeight(values=values, indices=indices, bitmask=bitmask,
                      scale=scale, block=block, nnz=nnz, k_dim=k_dim)
 

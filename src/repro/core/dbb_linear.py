@@ -130,7 +130,7 @@ def pack_tree(params: Any, cfg: DbbConfig, quantize: bool = False) -> Any:
         fn = pack_one
         for _ in range(leaf.ndim - 2):
             fn = jax.vmap(fn)
-        p = fn(leaf)
+        p = jax.jit(fn)(leaf)      # one fused program: bounded temporaries
         # serving format drops the diagnostic int32 indices (4 B/value —
         # 4x the int8 payload); kernels and decompress consume the bitmask
         return DbbWeight(values=p.values, indices=None,

@@ -12,9 +12,9 @@ from typing import Optional
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.compat import shard_map
 from repro.dist.mesh_ctx import current_mesh
 
 __all__ = ["dense_ce", "dense_ce_chunked", "vocab_parallel_ce",

@@ -47,9 +47,25 @@ def shard_tp() -> int:
     return _SHARD_TP.get()
 
 
+def _auto_axes(mesh: Optional[Mesh]) -> Optional[Mesh]:
+    """The same devices and axis names with every axis ``Auto``.
+    `jax.make_mesh` defaults to ``Explicit`` axes, under which every
+    jitted computation must itself run inside ``jax.set_mesh``; the model
+    code here places work with GSPMD sharding constraints and shard_map
+    instead, which is what ``Auto`` axes mean."""
+    auto = jax.sharding.AxisType.Auto
+    if mesh is None or all(t == auto for t in mesh.axis_types):
+        return mesh
+    return Mesh(mesh.devices, mesh.axis_names,
+                axis_types=(auto,) * len(mesh.axis_names))
+
+
 @contextlib.contextmanager
 def use_mesh(mesh: Optional[Mesh]):
-    """Make `mesh` the session mesh for the dynamic extent of the block."""
+    """Make `mesh` the session mesh for the dynamic extent of the block.
+    A mesh built with ``Explicit`` axes (the `jax.make_mesh` default) is
+    taken with ``Auto`` axes over the same devices."""
+    mesh = _auto_axes(mesh)
     token = _MESH.set(mesh)
     try:
         yield mesh

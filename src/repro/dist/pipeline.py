@@ -12,9 +12,9 @@ from typing import Any, Callable
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
-from repro.dist.compat import shard_map
 
 __all__ = ["stack_stages", "pipeline_forward"]
 

@@ -7,8 +7,10 @@ ignores the KV grid dim (revisited once per KV block), with running
 (m, l, acc) scratch guarded by first/last-visit ``pl.when``. The score
 tile ``[bq, bkv]`` is a kernel-body intermediate, not a BlockSpec, so
 it rides in ``extra_vmem_bytes`` — the same term `_footprint` charges.
-The paged decode contract closes its KV index maps over a concrete
-identity block table, mirroring the scalar-prefetch indirection.
+The per-row start / q_offset scalars and the decode block table are
+scalar-prefetched (SMEM), not VMEM blocks; the paged decode contract
+closes its KV index maps over a concrete identity block table,
+mirroring that indirection.
 """
 from __future__ import annotations
 
@@ -47,10 +49,6 @@ def _flash(b: int, hq: int, hkv: int, t: int, s: int, d: int,
             BlockDecl("v", (1, 1, bkv, d),
                       lambda bb, h, i, j: (bb, h // g, j, 0),
                       (b, hkv, sp, d), itemsize),
-            BlockDecl("start", (1, 1), lambda bb, h, i, j: (bb, 0),
-                      (b, 1), 4),
-            BlockDecl("q_offset", (1, 1), lambda bb, h, i, j: (bb, 0),
-                      (b, 1), 4),
         ),
         outputs=(BlockDecl("out", (1, 1, bq, d),
                            lambda bb, h, i, j: (bb, h, i, 0),
@@ -106,32 +104,33 @@ def _paged(b: int, hkv: int, g: int, d: int, page: int, n_log: int,
     tab = (np.arange(b, dtype=np.int32)[:, None] * n_log
            + np.arange(n_log, dtype=np.int32)[None, :])
 
-    def kv_map(bb, h, j):
-        return (int(tab[bb, j]), 0, h, 0)
+    def kv_map(bb, j):
+        return (int(tab[bb, j]), 0, 0, 0)
 
-    ok = paged_decode_ok(page, d, itemsize) and skinny_ok(g, d, itemsize)
+    ok = (paged_decode_ok(page, hkv, d, itemsize)
+          and skinny_ok(g, d, itemsize))
     return KernelContract(
         name=f"attn_decode[b{b} h{hkv} g{g} d{d} p{page}x{n_log}]",
         route="attn_decode_flash", domain="attn_decode",
-        grid=(b, hkv, n_log),
-        dimension_semantics=("parallel", "parallel", "arbitrary"),
+        grid=(b, n_log),
+        dimension_semantics=("parallel", "arbitrary"),
         inputs=(
-            BlockDecl("q", (1, 1, gp, d), lambda bb, h, j: (bb, h, 0, 0),
+            BlockDecl("q", (1, hkv, gp, d), lambda bb, j: (bb, 0, 0, 0),
                       (b, hkv, gp, d), itemsize),
-            BlockDecl("k_pages", (1, page, 1, d), kv_map,
+            BlockDecl("k_pages", (1, page, hkv, d), kv_map,
                       (n_phys, page, hkv, d), itemsize),
-            BlockDecl("v_pages", (1, page, 1, d), kv_map,
+            BlockDecl("v_pages", (1, page, hkv, d), kv_map,
                       (n_phys, page, hkv, d), itemsize),
         ),
-        outputs=(BlockDecl("out", (1, 1, gp, d),
-                           lambda bb, h, j: (bb, h, 0, 0),
+        outputs=(BlockDecl("out", (1, hkv, gp, d),
+                           lambda bb, j: (bb, 0, 0, 0),
                            (b, hkv, gp, d), itemsize),),
-        scratch=(ScratchDecl("m", (gp, 128), 4),
-                 ScratchDecl("l", (gp, 128), 4),
-                 ScratchDecl("acc", (gp, d), 4)),
-        acc_dims=(2,), guarded_init=True, guarded_store=True,
+        scratch=(ScratchDecl("m", (hkv, gp, 128), 4),
+                 ScratchDecl("l", (hkv, gp, 128), 4),
+                 ScratchDecl("acc", (hkv, gp, d), 4)),
+        acc_dims=(1,), guarded_init=True, guarded_store=True,
         vmem_budget=KERNEL_VMEM_BUDGET,
-        extra_vmem_bytes=gp * page * 4,     # score tile
+        extra_vmem_bytes=gp * page * 4,     # one head's score tile
         admitted=ok, vmem_reject=not ok,
         notes="KV index maps close over an identity block table "
               "(scalar-prefetch indirection)")

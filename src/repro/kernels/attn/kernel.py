@@ -34,7 +34,8 @@ final normalization divides by ``max(l, 1e-30)``.
 Shape contract (pad at the ops layer):
     prefill: q [B, Hq, T, D], k/v [B, Hkv, S, D], start [B, 1] int32,
              T % block_q == 0, S % block_kv == 0, Hq % Hkv == 0
-    decode:  q [B, Hkv, G, D], k/v pages [P, page, Hkv, D],
+    decode:  q [B, Hkv, G, D], k/v pages [P, page, Hkv, D] (one grid step
+             reads one page of every head),
              block table [B, n_log] int32, lengths/start [B] int32
 """
 from __future__ import annotations
@@ -78,13 +79,15 @@ def _online_update(s, v, m_ref, l_ref, acc_ref):
 # prefill
 # ---------------------------------------------------------------------------
 
-def _flash_prefill_kernel(q_ref, k_ref, v_ref, start_ref, qoff_ref, o_ref,
+def _flash_prefill_kernel(start_ref, qoff_ref, q_ref, k_ref, v_ref, o_ref,
                           m_ref, l_ref, acc_ref, *, n_kv: int, block_q: int,
                           block_kv: int, sm_scale: float, window: int,
                           softcap: float, out_dtype):
+    bb = pl.program_id(0)
     i = pl.program_id(2)
     j = pl.program_id(3)
-    qoff = qoff_ref[0, 0]        # chunked-prefill continuation offset (§12)
+    start = start_ref[bb]
+    qoff = qoff_ref[bb]          # chunked-prefill continuation offset (§12)
     qi0 = qoff + i * block_q
     kj0 = j * block_kv
 
@@ -99,7 +102,7 @@ def _flash_prefill_kernel(q_ref, k_ref, v_ref, start_ref, qoff_ref, o_ref,
     # and kj_max past the row's left padding (fully-pad blocks of a ragged
     # batch contribute nothing — the alpha washout would discard them)
     run = kj0 <= qi0 + block_q - 1
-    run &= kj0 + block_kv - 1 >= start_ref[0, 0]
+    run &= kj0 + block_kv - 1 >= start
     if window > 0:
         run &= kj0 + block_kv - 1 > qi0 - window
 
@@ -113,7 +116,7 @@ def _flash_prefill_kernel(q_ref, k_ref, v_ref, start_ref, qoff_ref, o_ref,
         s = _softcap(s, softcap)
         qi = qi0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 0)
         kj = kj0 + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
-        mask = (kj <= qi) & (kj >= start_ref[0, 0])
+        mask = (kj <= qi) & (kj >= start)
         if window > 0:
             mask &= kj > qi - window
         s = jnp.where(mask, s, NEG_INF)
@@ -129,8 +132,8 @@ def flash_prefill_pallas(
     q: jax.Array,                 # [B, Hq, T, D]
     k: jax.Array,                 # [B, Hkv, S, D]
     v: jax.Array,                 # [B, Hkv, S, D]
-    start: Optional[jax.Array] = None,    # [B, 1] int32, first real key slot
-    q_offset: Optional[jax.Array] = None,  # [B, 1] int32, abs pos of q row 0
+    start: Optional[jax.Array] = None,    # [B] int32, first real key slot
+    q_offset: Optional[jax.Array] = None,  # [B] int32, abs pos of q row 0
     *,
     sm_scale: float,
     window: int = 0,
@@ -142,7 +145,7 @@ def flash_prefill_pallas(
     """Causal (+ sliding window, + left-pad) flash attention over a full
     sequence. Returns o [B, Hq, T, D] in q.dtype.
 
-    q_offset [B, 1] (optional): absolute key-slot position of query row 0 —
+    q_offset [B] (optional): absolute key-slot position of query row 0 —
     the chunked-prefill continuation case (DESIGN.md §12), where a chunk of
     queries at absolute positions ``offset .. offset+T-1`` attends a cache
     of S >= offset+T key slots. Zero (the default) is the ordinary
@@ -155,40 +158,46 @@ def flash_prefill_pallas(
         f"(T={t}, S={s_len}) not divisible by blocks "
         f"({block_q},{block_kv}); pad at the ops layer")
     if start is None:
-        start = jnp.zeros((b, 1), jnp.int32)
+        start = jnp.zeros((b,), jnp.int32)
     if q_offset is None:
-        q_offset = jnp.zeros((b, 1), jnp.int32)
+        q_offset = jnp.zeros((b,), jnp.int32)
     n_q, n_kv = t // block_q, s_len // block_kv
 
     kernel = functools.partial(
         _flash_prefill_kernel, n_kv=n_kv, block_q=block_q,
         block_kv=block_kv, sm_scale=sm_scale, window=window,
         softcap=softcap, out_dtype=q.dtype)
-    return pl.pallas_call(
-        kernel,
+    # start / q_offset are per-row scalars: scalar-prefetched into SMEM
+    # (a [1, 1] VMEM block over [B, 1] is not a legal TPU tile)
+    grid_spec = pltpu.PrefetchScalarGridSpec(
+        num_scalar_prefetch=2,
         grid=(b, hq, n_q, n_kv),
         in_specs=[
-            pl.BlockSpec((1, 1, block_q, d), lambda bb, h, i, j: (bb, h, i, 0)),
+            pl.BlockSpec((1, 1, block_q, d),
+                         lambda bb, h, i, j, st, qo: (bb, h, i, 0)),
             pl.BlockSpec((1, 1, block_kv, d),
-                         lambda bb, h, i, j: (bb, h // g, j, 0)),
+                         lambda bb, h, i, j, st, qo: (bb, h // g, j, 0)),
             pl.BlockSpec((1, 1, block_kv, d),
-                         lambda bb, h, i, j: (bb, h // g, j, 0)),
-            pl.BlockSpec((1, 1), lambda bb, h, i, j: (bb, 0)),
-            pl.BlockSpec((1, 1), lambda bb, h, i, j: (bb, 0)),
+                         lambda bb, h, i, j, st, qo: (bb, h // g, j, 0)),
         ],
         out_specs=pl.BlockSpec((1, 1, block_q, d),
-                               lambda bb, h, i, j: (bb, h, i, 0)),
-        out_shape=jax.ShapeDtypeStruct((b, hq, t, d), q.dtype),
+                               lambda bb, h, i, j, st, qo: (bb, h, i, 0)),
         scratch_shapes=[
             pltpu.VMEM((block_q, 128), jnp.float32),    # running max m
             pltpu.VMEM((block_q, 128), jnp.float32),    # running sum l
             pltpu.VMEM((block_q, d), jnp.float32),      # output accumulator
         ],
+    )
+    return pl.pallas_call(
+        kernel,
+        grid_spec=grid_spec,
+        out_shape=jax.ShapeDtypeStruct((b, hq, t, d), q.dtype),
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "parallel", "parallel",
                                  "arbitrary")),
         interpret=interpret,
-    )(q, k, v, start, q_offset)
+    )(jnp.asarray(start, jnp.int32).reshape(b),
+      jnp.asarray(q_offset, jnp.int32).reshape(b), q, k, v)
 
 
 # ---------------------------------------------------------------------------
@@ -329,10 +338,10 @@ def flash_prefill_packed_pallas(
 
 def _paged_decode_kernel(tab_ref, len_ref, start_ref, q_ref, k_ref, v_ref,
                          o_ref, m_ref, l_ref, acc_ref, *, n_log: int,
-                         page: int, sm_scale: float, window: int,
+                         page: int, hkv: int, sm_scale: float, window: int,
                          softcap: float, out_dtype):
     bb = pl.program_id(0)
-    j = pl.program_id(2)
+    j = pl.program_id(1)
     length = len_ref[bb]                                # current token's slot
 
     @pl.when(j == 0)
@@ -350,23 +359,29 @@ def _paged_decode_kernel(tab_ref, len_ref, start_ref, q_ref, k_ref, v_ref,
 
     @pl.when(run)
     def _compute():
-        q = q_ref[0, 0]                                 # [G, D]
-        k = k_ref[0, :, 0]                              # [page, D]
-        s = jax.lax.dot_general(
-            q, k, dimension_numbers=(((1,), (1,)), ((), ())),
-            preferred_element_type=jnp.float32) * sm_scale
-        s = _softcap(s, softcap)
-        kk = j * page + jax.lax.broadcasted_iota(jnp.int32, s.shape, 1)
+        g = q_ref.shape[2]
+        kk = j * page + jax.lax.broadcasted_iota(jnp.int32, (g, page), 1)
         mask = (kk <= length) & (kk >= start_ref[bb])
         if window > 0:
             mask &= kk > length - window
-        s = jnp.where(mask, s, NEG_INF)
-        _online_update(s, v_ref[0, :, 0], m_ref, l_ref, acc_ref)
+        # the page block holds every KV head (a one-head slice of the
+        # [page, Hkv, D] page is not a legal TPU tile); each head is a
+        # strided read of it with its own online-softmax state
+        for h in range(hkv):
+            q = q_ref[0, h]                             # [G, D]
+            k = k_ref[0, :, h, :]                       # [page, D]
+            s = jax.lax.dot_general(
+                q, k, dimension_numbers=(((1,), (1,)), ((), ())),
+                preferred_element_type=jnp.float32) * sm_scale
+            s = jnp.where(mask, _softcap(s, softcap), NEG_INF)
+            _online_update(s, v_ref[0, :, h, :], m_ref.at[h], l_ref.at[h],
+                           acc_ref.at[h])
 
     @pl.when(j == n_log - 1)
     def _store():
-        l = jnp.maximum(l_ref[:, :1], _L_EPS)
-        o_ref[0, 0] = (acc_ref[...] / l).astype(out_dtype)
+        for h in range(hkv):
+            l = jnp.maximum(l_ref[h, :, :1], _L_EPS)
+            o_ref[0, h] = (acc_ref[h] / l).astype(out_dtype)
 
 
 def paged_decode_pallas(
@@ -384,34 +399,36 @@ def paged_decode_pallas(
 ) -> jax.Array:
     """One-token decode attention over a paged KV cache. The block table is
     scalar-prefetched so it drives the KV page DMA index map: logical page
-    ``j`` of row ``b`` is fetched from physical page ``block_table[b, j]``.
-    Returns o [B, Hkv, G, D] in q.dtype. The new token's K/V must already
-    be scattered into the pool (slot ``lengths[b]``)."""
+    ``j`` of row ``b`` is fetched from physical page ``block_table[b, j]``,
+    all KV heads at once. Returns o [B, Hkv, G, D] in q.dtype. The new
+    token's K/V must already be scattered into the pool (slot
+    ``lengths[b]``)."""
     b, hkv, g, d = q.shape
     _, page, hkv2, _ = k_pages.shape
     assert hkv2 == hkv, (k_pages.shape, q.shape)
     n_log = block_table.shape[1]
 
     kernel = functools.partial(
-        _paged_decode_kernel, n_log=n_log, page=page, sm_scale=sm_scale,
-        window=window, softcap=softcap, out_dtype=q.dtype)
+        _paged_decode_kernel, n_log=n_log, page=page, hkv=hkv,
+        sm_scale=sm_scale, window=window, softcap=softcap,
+        out_dtype=q.dtype)
     grid_spec = pltpu.PrefetchScalarGridSpec(
         num_scalar_prefetch=3,
-        grid=(b, hkv, n_log),
+        grid=(b, n_log),
         in_specs=[
-            pl.BlockSpec((1, 1, g, d),
-                         lambda bb, h, j, tab, ln, st: (bb, h, 0, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bb, h, j, tab, ln, st: (tab[bb, j], 0, h, 0)),
-            pl.BlockSpec((1, page, 1, d),
-                         lambda bb, h, j, tab, ln, st: (tab[bb, j], 0, h, 0)),
+            pl.BlockSpec((1, hkv, g, d),
+                         lambda bb, j, tab, ln, st: (bb, 0, 0, 0)),
+            pl.BlockSpec((1, page, hkv, d),
+                         lambda bb, j, tab, ln, st: (tab[bb, j], 0, 0, 0)),
+            pl.BlockSpec((1, page, hkv, d),
+                         lambda bb, j, tab, ln, st: (tab[bb, j], 0, 0, 0)),
         ],
-        out_specs=pl.BlockSpec((1, 1, g, d),
-                               lambda bb, h, j, tab, ln, st: (bb, h, 0, 0)),
+        out_specs=pl.BlockSpec((1, hkv, g, d),
+                               lambda bb, j, tab, ln, st: (bb, 0, 0, 0)),
         scratch_shapes=[
-            pltpu.VMEM((g, 128), jnp.float32),          # running max m
-            pltpu.VMEM((g, 128), jnp.float32),          # running sum l
-            pltpu.VMEM((g, d), jnp.float32),            # output accumulator
+            pltpu.VMEM((hkv, g, 128), jnp.float32),     # running max m
+            pltpu.VMEM((hkv, g, 128), jnp.float32),     # running sum l
+            pltpu.VMEM((hkv, g, d), jnp.float32),       # output accumulator
         ],
     )
     return pl.pallas_call(
@@ -419,6 +436,6 @@ def paged_decode_pallas(
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=CompilerParams(
-            dimension_semantics=("parallel", "parallel", "arbitrary")),
+            dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
     )(block_table, lengths, start, q, k_pages, v_pages)

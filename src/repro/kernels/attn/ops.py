@@ -78,17 +78,26 @@ def flash_ok(t: int, s: int, d: int, itemsize: int) -> bool:
     return _footprint(SUBLANE, SUBLANE, d, itemsize) <= KERNEL_VMEM_BUDGET
 
 
-def paged_decode_ok(page: int, d: int, itemsize: int) -> bool:
+def decode_footprint(gp: int, page: int, hkv: int, d: int,
+                     itemsize: int) -> int:
+    """Decode VMEM working set: the q/out blocks and one KV page of every
+    head, one head's score tile, and the per-head (m, l, acc) f32
+    scratch."""
+    return ((2 * hkv * gp * d + 2 * page * hkv * d) * itemsize
+            + gp * page * 4 + hkv * (2 * gp * 128 + gp * d) * 4)
+
+
+def paged_decode_ok(page: int, hkv: int, d: int, itemsize: int) -> bool:
     """VMEM guard for the decode kernel: the page is its KV tile size, and
     unlike the prefill blocks it comes straight from user config
     (``kv_page_size`` / ``--kv-page-size``), so an oversized page must be
     rejected up front (contiguous decode falls back to the XLA path; the
     paged engine refuses at pool construction) rather than failing in the
     Mosaic lowering mid-serving. Budgeted at the worst-case resident query
-    block (SKINNY_M_MAX rows)."""
+    block (SKINNY_M_MAX rows per head)."""
     from repro.kernels.common import SKINNY_M_MAX
-    return _footprint(round_up(SKINNY_M_MAX, SUBLANE), page, d,
-                      itemsize) <= KERNEL_VMEM_BUDGET
+    return decode_footprint(round_up(SKINNY_M_MAX, SUBLANE), page, hkv, d,
+                            itemsize) <= KERNEL_VMEM_BUDGET
 
 
 def _autotuned_blocks(t: int, s: int, d: int, dtype, window: int,

@@ -5,15 +5,11 @@ import functools
 
 import jax
 import jax.numpy as jnp
+from jax.experimental.pallas import tpu as pltpu
 
 from repro.core.sta import KERNEL_VMEM_BUDGET, SUBLANE, VMEM_BYTES
 
-try:  # jax >= 0.7 name
-    from jax.experimental.pallas import tpu as pltpu
-    CompilerParams = pltpu.CompilerParams
-except AttributeError:  # pragma: no cover - older naming
-    from jax.experimental.pallas import tpu as pltpu
-    CompilerParams = pltpu.TPUCompilerParams  # type: ignore[attr-defined]
+CompilerParams = pltpu.CompilerParams
 
 __all__ = ["pltpu", "CompilerParams", "on_cpu", "default_interpret",
            "cdiv", "round_up", "popcount_u32", "acc_dtype_for",

@@ -16,8 +16,9 @@ Decomposition (DESIGN.md §8):
     grid = (B, Ho/th, N/bn, kh)       th output rows per M tile, bm = th·Wo
     K step i (one kernel ROW offset, kw·C contraction columns):
       slab  = x[0, i + t0·s : i + t0·s + (th-1)·s + 1 : s, :, :]   # th rows
-      patch = stack_j slab[:, j : j+(Wo-1)·s+1 : s, :]             # [th,Wo,kw,C]
-      acc  += patch.reshape(th·Wo, kw·C) @ w_tile                  # MXU dot
+      for j < kw:                                 # patch column block j
+        acc += slab[:, j : j+(Wo-1)·s+1 : s, :].reshape(th·Wo, C)
+               @ w_tile[j·C : (j+1)·C]                              # MXU dot
 
 The patch gather is a dynamic-start row slice plus kw static shifted
 column slices of the VMEM-resident image block — no HBM gather, no
@@ -49,31 +50,43 @@ from repro.kernels.epilogue import Epilogue, apply_epilogue, default_out_dtype
 __all__ = ["conv_gemm_pallas", "conv_gemm_dbb_pallas"]
 
 
-def _gather_patch_tile(x_ref, *, th: int, wo: int, kw: int, stride: int):
-    """In-kernel im2col of one M×K tile: [th·wo, kw·C] patch rows for the
-    current (image-row tile, kernel-row offset) grid step.
+def _patch_slabs(x_ref, *, th: int, wo: int, kw: int, stride: int):
+    """In-kernel im2col of one M×K tile, as kw ``[th·wo, C]`` slabs: slab
+    ``j`` holds the patch rows' contraction columns ``j·C .. (j+1)·C-1``
+    for the current (image-row tile, kernel-row offset) grid step.
 
     x_ref block is the whole padded image [1, Hp, Wp, C]; the row slab is a
-    dynamic-start slice (start depends on grid ids), the kw column shifts
-    are static strided slices of the loaded slab."""
+    dynamic-start slice (start depends on grid ids) and the kw column
+    shifts are static slices — of the loaded slab at stride 1, strided
+    reads of the ref otherwise (Mosaic: 32-bit images only). The slabs are
+    never stacked into one [th·wo, kw·C] patch: that rank-changing stack
+    is a shape cast the TPU compiler refuses, so each slab meets its own
+    rows of the weight tile instead (`_accumulate`)."""
     ih = pl.program_id(1)                  # output-row tile index
     ki = pl.program_id(3)                  # kernel row offset i ∈ [0, kh)
-    rows = (th - 1) * stride + 1
     r0 = ih * (th * stride) + ki
-    slab = x_ref[0, pl.ds(r0, rows)]       # [rows, Wp, C]
-    if stride > 1:
-        slab = slab[::stride]              # [th, Wp, C]
-    cols = (wo - 1) * stride + 1
-    parts = [slab[:, j:j + cols:stride, :] for j in range(kw)]
-    patch = jnp.stack(parts, axis=2)       # [th, wo, kw, C]
-    c = patch.shape[-1]
-    return patch.reshape(th * wo, kw * c)  # K order: j-major, c-minor
+    c = x_ref.shape[-1]
+    if stride == 1:
+        slab = x_ref[0, pl.ds(r0, th)]     # [th, Wp, C]
+        return [slab[:, j:j + wo, :].reshape(th * wo, c) for j in range(kw)]
+    # strided reads straight from the ref (a strided slice of a loaded
+    # value is a gather the TPU compiler refuses)
+    return [x_ref[0, pl.ds(r0, th, stride=stride),
+                  pl.ds(j, wo, stride=stride), :].reshape(th * wo, c)
+            for j in range(kw)]
 
 
-def _accumulate(acc_ref, patch, w):
-    acc_ref[...] += jax.lax.dot_general(
-        patch, w, dimension_numbers=(((1,), (0,)), ((), ())),
-        preferred_element_type=acc_ref.dtype)
+def _accumulate(acc_ref, slabs, w):
+    """acc += patch @ w, one kw slab at a time: K order is j-major,
+    c-minor, so slab j meets weight rows ``j·C .. (j+1)·C-1``."""
+    c = slabs[0].shape[-1]
+    acc = acc_ref[...]
+    for j, slab in enumerate(slabs):
+        acc += jax.lax.dot_general(
+            slab, w[j * c:(j + 1) * c],
+            dimension_numbers=(((1,), (0,)), ((), ())),
+            preferred_element_type=acc_ref.dtype)
+    acc_ref[...] = acc
 
 
 def _store_epilogue(o_ref, acc_ref, bias_ref, scale_ref, *, epilogue,
@@ -97,8 +110,8 @@ def _conv_gemm_kernel(x_ref, w_ref, *refs, kh: int, kw: int, stride: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    patch = _gather_patch_tile(x_ref, th=th, wo=wo, kw=kw, stride=stride)
-    _accumulate(acc_ref, patch, w_ref[...])
+    slabs = _patch_slabs(x_ref, th=th, wo=wo, kw=kw, stride=stride)
+    _accumulate(acc_ref, slabs, w_ref[...])
 
     @pl.when(ki == kh - 1)
     def _store():
@@ -123,9 +136,9 @@ def _conv_gemm_dbb_kernel(x_ref, v_ref, m_ref, *refs, kh: int, kw: int,
     def _init():
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
-    patch = _gather_patch_tile(x_ref, th=th, wo=wo, kw=kw, stride=stride)
+    slabs = _patch_slabs(x_ref, th=th, wo=wo, kw=kw, stride=stride)
     w = _decompress_tile(v_ref[...], m_ref[...], block=block, nnz=nnz)
-    _accumulate(acc_ref, patch, w.astype(patch.dtype))
+    _accumulate(acc_ref, slabs, w.astype(slabs[0].dtype))
 
     @pl.when(ki == kh - 1)
     def _store():

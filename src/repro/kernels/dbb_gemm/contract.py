@@ -36,21 +36,20 @@ def _instance(m: int, k: int, n: int, *, block: int = 8, nnz: int = 4,
     inputs = [BlockDecl("x", (bm, bk), lambda i, j, kk: (i, kk), (mp, kp),
                         itemsize)]
     if bits == 4:
-        gpt = max(bk // group, 1)      # scale groups covered per K tile
-        gdiv = max(group // bk, 1)
         inputs += [
             # nibble plane: two compressed rows per streamed byte row
             BlockDecl("values", (bkc // 2, bn), lambda i, j, kk: (kk, j),
                       (nb_total * nnz // 2, np_), 1),
             BlockDecl("bitmask", (nb_tile, bn), lambda i, j, kk: (kk, j),
                       (nb_total, np_), 4),
-            BlockDecl("gscale", (gpt, bn),
-                      lambda i, j, kk: (kk // gdiv, j),
-                      (kp // group, np_), 4),
+            # the whole [K/G, bn] scale column, rows sliced per K tile
+            BlockDecl("gscale", (kp // group, bn),
+                      lambda i, j, kk: (0, j), (kp // group, np_), 4),
         ]
-        # expansion chain per K step (DESIGN.md §16): unpacked int8
-        # slots + dense int8 tile + dequantized f32 tile
-        extra = bkc * bn + bk * bn + bk * bn * 4
+        # expansion chain per K step (DESIGN.md §16): int32 slots (the
+        # sign-extension shifts run on int32) + dense int32 tile +
+        # dequantized f32 tile
+        extra = 4 * (bkc * bn + bk * bn + bk * bn)
     else:
         inputs += [
             BlockDecl("values", (bkc, bn), lambda i, j, kk: (kk, j),

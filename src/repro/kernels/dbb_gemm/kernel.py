@@ -56,19 +56,30 @@ def _decompress_tile(vals, mask, *, block: int, nnz: int):
 
 
 def _expand_nibbles(packed):
-    """Sign-extend a nibble-packed int8 tile ``[r/2, bn] → [r, bn]``:
-    packed row i holds compressed row 2i (low nibble, ``(p << 4) >> 4``)
+    """Sign-extend a nibble-packed int8 tile ``[r/2, bn] → [r, bn]`` int32:
+    packed row i holds compressed row 2i (low nibble, ``(p << 28) >> 28``)
     and row 2i+1 (high nibble, ``p >> 4``) — pure VPU shift arithmetic,
-    the in-kernel mirror of `core.dbb.unpack_nibbles`."""
+    the in-kernel mirror of `core.dbb.unpack_nibbles`. The shifts run on
+    int32 because the TPU VPU has no int8 shifts."""
     r2, bn = packed.shape
-    lo = jnp.right_shift(jnp.left_shift(packed, 4), 4)
-    hi = jnp.right_shift(packed, 4)
+    p = packed.astype(jnp.int32)
+    lo = jnp.right_shift(jnp.left_shift(p, 28), 28)
+    hi = jnp.right_shift(p, 4)
     return jnp.stack([lo, hi], axis=1).reshape(r2 * 2, bn)
 
 
+def group_scale_rows(gs_ref, kk, *, block_k: int, group: int):
+    """The ``[gpt, bn]`` groupwise scales covering K tile ``kk``, read
+    from the grid-constant ``[K/G, bn]`` scale block (a block of one scale
+    row per tile would be a second-minor dim of 1, which Mosaic refuses).
+    When a group spans several K tiles, successive tiles reread its row."""
+    gpt = max(block_k // group, 1)
+    return gs_ref[pl.ds((kk * block_k) // group, gpt), :]
+
+
 def _dequant_tile(vals, mask, gscale, *, block: int, nnz: int):
-    """w4 decompress-tile step: expand the nibble plane to int8, bitmask-
-    rank decompress to the dense [bk, bn] tile, then dequantize with the
+    """w4 decompress-tile step: expand the nibble plane, bitmask-rank
+    decompress to the dense [bk, bn] tile, then dequantize with the
     per-group scales ``gscale [gpt, bn]`` (gpt groups cover the K tile).
     All in VMEM — neither the int8-expanded nor the dense weight ever
     exists in HBM."""
@@ -79,9 +90,9 @@ def _dequant_tile(vals, mask, gscale, *, block: int, nnz: int):
     return w.reshape(bk, bn)
 
 
-def _dbb_gemm_kernel(x_ref, v_ref, m_ref, *refs, n_k: int, block: int,
-                     nnz: int, out_dtype, epilogue: Epilogue,
-                     bits: int = 8):
+def _dbb_gemm_kernel(x_ref, v_ref, m_ref, *refs, n_k: int, block_k: int,
+                     block: int, nnz: int, out_dtype, epilogue: Epilogue,
+                     bits: int = 8, group: int = 0):
     refs = list(refs)
     gs_ref = refs.pop(0) if bits == 4 else None
     bias_ref = refs.pop(0) if epilogue.has_bias else None
@@ -94,8 +105,8 @@ def _dbb_gemm_kernel(x_ref, v_ref, m_ref, *refs, n_k: int, block: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     if bits == 4:
-        w = _dequant_tile(v_ref[...], m_ref[...], gs_ref[...],
-                          block=block, nnz=nnz)
+        gs = group_scale_rows(gs_ref, k, block_k=block_k, group=group)
+        w = _dequant_tile(v_ref[...], m_ref[...], gs, block=block, nnz=nnz)
     else:
         w = _decompress_tile(v_ref[...], m_ref[...], block=block, nnz=nnz)
     acc_ref[...] += jax.lax.dot_general(
@@ -180,13 +191,11 @@ def dbb_gemm_pallas(
         pl.BlockSpec((nb_tile, block_n), lambda i, j, kk: (kk, j)),
     ]
     if bits == 4:
-        # gpt scale rows cover one K tile; when the group spans several K
-        # tiles (gdiv of them), successive kk revisit the same scale row.
-        gpt = max(block_k // group, 1)
-        gdiv = max(group // block_k, 1)
+        # the whole [K/G, bn] scale column stays resident across the K
+        # loop; the kernel slices the rows of each K tile
         operands.append(gscale)
-        in_specs.append(pl.BlockSpec((gpt, block_n),
-                                     lambda i, j, kk: (kk // gdiv, j)))
+        in_specs.append(pl.BlockSpec((k_dim // group, block_n),
+                                     lambda i, j, kk: (0, j)))
     row_spec = pl.BlockSpec((1, block_n), lambda i, j, kk: (0, j))
     if epilogue.has_bias:
         assert bias is not None and bias.shape == (1, n), (
@@ -200,9 +209,9 @@ def dbb_gemm_pallas(
         in_specs.append(row_spec)
 
     grid = (m // block_m, n // block_n, n_k)
-    kernel = functools.partial(_dbb_gemm_kernel, n_k=n_k, block=block,
-                               nnz=nnz, out_dtype=out_dtype,
-                               epilogue=epilogue, bits=bits)
+    kernel = functools.partial(_dbb_gemm_kernel, n_k=n_k, block_k=block_k,
+                               block=block, nnz=nnz, out_dtype=out_dtype,
+                               epilogue=epilogue, bits=bits, group=group)
     return pl.pallas_call(
         kernel,
         grid=grid,

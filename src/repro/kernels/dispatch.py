@@ -36,6 +36,8 @@ resolved once per compiled shape, exactly like the old inline guards.
 """
 from __future__ import annotations
 
+import contextlib
+import contextvars
 import dataclasses
 import math
 import os
@@ -54,7 +56,7 @@ __all__ = [
     "select", "explain", "format_table", "matmul", "conv", "attention",
     "head_sample", "decode_attention_route", "pallas_route_active",
     "flash_backend_active", "forced_route", "routes_from_cfg",
-    "FORCE_ROUTE_ENV", "COST_TIE_RTOL", "DOMAINS",
+    "record_routes", "FORCE_ROUTE_ENV", "COST_TIE_RTOL", "DOMAINS",
 ]
 
 FORCE_ROUTE_ENV = "REPRO_FORCE_ROUTE"
@@ -113,8 +115,9 @@ class OpSpec:
     # cost must scale by; packed specs keep batch=1 since m already IS the
     # whole batch's token count)
     batch: int = 1
-    # decode extras
+    # decode extras (the kernel reads one page of every KV head per step)
     page: int = 0
+    kv_heads: int = 1
     ring: bool = False
     # head_sample extras: top-k/top-p active for some row — they are
     # global order statistics, which the streaming fused epilogue cannot
@@ -290,6 +293,33 @@ def _decide(route: Route, spec: OpSpec, hw: Hardware) -> RouteDecision:
 
 
 _warned_forced: set = set()
+
+# (domain, route) picks of the front doors, while `record_routes` is open
+_ROUTE_LOG: contextvars.ContextVar[Optional[set]] = contextvars.ContextVar(
+    "repro_route_log", default=None)
+
+
+@contextlib.contextmanager
+def record_routes():
+    """Collect the ``(domain, route)`` pairs the front doors pick inside
+    the block. Picks happen at trace time, so a step whose compiled
+    program is already cached adds none."""
+    log: set = set()
+    token = _ROUTE_LOG.set(log)
+    try:
+        yield log
+    finally:
+        _ROUTE_LOG.reset(token)
+
+
+def _pick(spec: OpSpec, cfg_routes: Optional[Dict[str, str]] = None) -> str:
+    """`select` for a real call site: the chosen name, logged for
+    `record_routes`."""
+    name, _ = select(spec, cfg_routes)
+    log = _ROUTE_LOG.get()
+    if log is not None:
+        log.add((spec.domain, name))
+    return name
 
 
 def select(spec: OpSpec, cfg_routes: Optional[Dict[str, str]] = None,
@@ -763,7 +793,7 @@ def matmul(x: jax.Array, w, bias=None, scale=None, *, act: str = "none",
                              f"{dec.reason}")
         name = route
     else:
-        name, _ = select(spec, routes_from_cfg(cfg))
+        name = _pick(spec, routes_from_cfg(cfg))
 
     kw = dict(block_m=block_m, block_k=block_k, block_n=block_n)
     if name in ("sta", "skinny_sta"):
@@ -984,7 +1014,7 @@ def conv(x: jax.Array, w, bias=None, *, kh: int, kw: int, stride: int = 1,
                              f"{dec.reason}")
         name = route
     else:
-        name, _ = select(spec, routes_from_cfg(cfg))
+        name = _pick(spec, routes_from_cfg(cfg))
 
     from repro.kernels.conv_gemm.ops import conv_gemm, conv_gemm_packed
     kernel = name != "conv_xla"
@@ -1127,7 +1157,7 @@ def attention(q: jax.Array, k: jax.Array, v: jax.Array,
     # compatibility; kernel_routes["attention"] wins if both are set)
     if cfg.attn_impl in _ATTN_IMPL_ROUTE:
         cfg_routes.setdefault("attention", _ATTN_IMPL_ROUTE[cfg.attn_impl])
-    name, _ = select(spec, cfg_routes)
+    name = _pick(spec, cfg_routes)
 
     if name == "attn_flash":
         from repro.kernels.attn import flash_attention
@@ -1163,7 +1193,7 @@ def chunk_attention_route(cfg, *, t: int, s: int, d: int, itemsize: int,
         cfg_routes["attention"] = "attn_naive"
     if cfg.attn_impl in _CHUNK_IMPL_ROUTE:
         cfg_routes.setdefault("attention", _CHUNK_IMPL_ROUTE[cfg.attn_impl])
-    name, _ = select(spec, cfg_routes)
+    name = _pick(spec, cfg_routes)
     return name
 
 
@@ -1187,7 +1217,7 @@ def packed_attention(q: jax.Array, k: jax.Array, v: jax.Array,
         cfg_routes["attention"] = _ATTN_TO_PACKED[cfg_routes["attention"]]
     if cfg.attn_impl in _PACKED_IMPL_ROUTE:
         cfg_routes.setdefault("attention", _PACKED_IMPL_ROUTE[cfg.attn_impl])
-    name, _ = select(spec, cfg_routes)
+    name = _pick(spec, cfg_routes)
     o = packed_flash_attention(
         q[0], k[0], v[0], seg_ids, window=cfg.sliding_window,
         softcap=cfg.attn_logit_softcap,
@@ -1216,7 +1246,7 @@ def _guard_decode_flash(spec: OpSpec) -> str:
     if spec.n % max(spec.page, 1) != 0:
         return f"cache length {spec.n} not a multiple of page {spec.page}"
     from repro.kernels.attn.ops import paged_decode_ok
-    if not paged_decode_ok(spec.page, spec.k, spec.itemsize):
+    if not paged_decode_ok(spec.page, spec.kv_heads, spec.k, spec.itemsize):
         return "KV page tile exceeds the decode kernel's VMEM budget"
     return ""
 
@@ -1239,16 +1269,16 @@ register_route(Route(
 
 
 def decode_attention_route(cfg, *, group: int, head_dim: int, itemsize: int,
-                           page: int, smax: int, ring: bool = False,
-                           floating: bool = True) -> str:
+                           page: int, smax: int, kv_heads: int = 1,
+                           ring: bool = False, floating: bool = True) -> str:
     """Route selection for one-token decode attention — the gate that used
     to live inline in `decode_attention_apply`. Returns a route name from
     the ``attn_decode`` domain."""
     spec = OpSpec(domain="attn_decode", m=group, k=head_dim, n=smax,
-                  itemsize=itemsize, out_itemsize=itemsize, page=page,
-                  ring=ring, flash_active=flash_backend_active(cfg),
-                  float_ok=floating)
-    name, _ = select(spec, routes_from_cfg(cfg))
+                  kv_heads=kv_heads, itemsize=itemsize,
+                  out_itemsize=itemsize, page=page, ring=ring,
+                  flash_active=flash_backend_active(cfg), float_ok=floating)
+    name = _pick(spec, routes_from_cfg(cfg))
     return name
 
 
@@ -1358,7 +1388,7 @@ def head_sample(h: jax.Array, w_head, counts: jax.Array, temp, rep, pres,
                              f"{dec.reason}")
         name = route
     else:
-        name, _ = select(spec, routes_from_cfg(cfg))
+        name = _pick(spec, routes_from_cfg(cfg))
 
     if name == "head_sample_fused":
         from repro.kernels.sample.ops import head_sample_fused

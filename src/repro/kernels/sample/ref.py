@@ -86,10 +86,12 @@ def hash_u32(seed: jax.Array, step: jax.Array, idx: jax.Array,
 
 
 def uniform_noise(seed, step, idx, salt: int) -> jax.Array:
-    """Uniform f32 strictly inside (0, 1); every op exact in f32."""
+    """Uniform f32 strictly inside (0, 1); every op exact in f32. The
+    23-bit draw goes through int32 (exact: ``h >> 9 < 2**23``) because the
+    TPU has no uint32 → f32 conversion."""
     h = hash_u32(seed, step, idx, salt)
-    return ((h >> np.uint32(9)).astype(jnp.float32) + np.float32(0.5)) \
-        * np.float32(2.0 ** -23)
+    top = (h >> np.uint32(9)).astype(jnp.int32)
+    return (top.astype(jnp.float32) + np.float32(0.5)) * np.float32(2.0 ** -23)
 
 
 def gumbel_noise(seed, step, idx, salt: int) -> jax.Array:

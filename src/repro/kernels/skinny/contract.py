@@ -43,8 +43,6 @@ def _instance(m: int, k: int, n: int, *, itemsize: int = 4,
         nb_total = kp // block
         kc_tile = nb_tile * nnz        # compressed (int8-slot) rows/tile
         if bits == 4:
-            gpt = max(bk // group, 1)  # scale groups covered per K tile
-            gdiv = max(group // bk, 1)
             inputs += [
                 # nibble plane: two compressed rows per streamed byte row
                 BlockDecl("values", (kc_tile // 2, bn),
@@ -52,14 +50,15 @@ def _instance(m: int, k: int, n: int, *, itemsize: int = 4,
                           (nb_total * nnz // 2, np_), 1),
                 BlockDecl("bitmask", (nb_tile, bn), lambda j, kk: (kk, j),
                           (nb_total, np_), 4),
-                BlockDecl("gscale", (gpt, bn),
-                          lambda j, kk: (kk // gdiv, j),
-                          (kp // group, np_), 4),
+                # the whole [K/G, bn] scale column, rows sliced per tile
+                BlockDecl("gscale", (kp // group, bn),
+                          lambda j, kk: (0, j), (kp // group, np_), 4),
             ]
             # expansion chain per tile, all live in VMEM at the
-            # decompress step: unpacked int8 slots + dense int8 tile +
-            # dequantized f32 tile (DESIGN.md §16)
-            extra = kc_tile * bn + bk * bn + bk * bn * 4
+            # decompress step: int32 slots (the sign-extension shifts run
+            # on int32) + dense int32 tile + dequantized f32 tile
+            # (DESIGN.md §16)
+            extra = 4 * (kc_tile * bn + bk * bn + bk * bn)
         else:
             inputs += [
                 BlockDecl("values", (kc_tile, bn),

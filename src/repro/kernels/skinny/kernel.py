@@ -35,7 +35,8 @@ from jax.experimental import pallas as pl
 from repro.core.sta import SUBLANE
 from repro.kernels.common import (SKINNY_M_MAX, CompilerParams, acc_dtype_for,
                                   pltpu, round_up, skinny_ok)
-from repro.kernels.dbb_gemm.kernel import _decompress_tile, _dequant_tile
+from repro.kernels.dbb_gemm.kernel import (_decompress_tile, _dequant_tile,
+                                          group_scale_rows)
 from repro.kernels.epilogue import Epilogue, apply_epilogue, default_out_dtype
 
 __all__ = ["SKINNY_M_MAX", "skinny_ok", "sta_gemm_skinny_pallas",
@@ -135,7 +136,7 @@ def sta_gemm_skinny_pallas(
 
 def _dbb_skinny_kernel(x_ref, v_ref, m_ref, *refs, n_k: int, block_k: int,
                        block: int, nnz: int, out_dtype, epilogue: Epilogue,
-                       bits: int = 8):
+                       bits: int = 8, group: int = 0):
     refs = list(refs)
     gs_ref = refs.pop(0) if bits == 4 else None
     bias_ref = refs.pop(0) if epilogue.has_bias else None
@@ -148,8 +149,8 @@ def _dbb_skinny_kernel(x_ref, v_ref, m_ref, *refs, n_k: int, block_k: int,
         acc_ref[...] = jnp.zeros_like(acc_ref)
 
     if bits == 4:
-        w = _dequant_tile(v_ref[...], m_ref[...], gs_ref[...],
-                          block=block, nnz=nnz)
+        gs = group_scale_rows(gs_ref, k, block_k=block_k, group=group)
+        w = _dequant_tile(v_ref[...], m_ref[...], gs, block=block, nnz=nnz)
     else:
         w = _decompress_tile(v_ref[...], m_ref[...], block=block, nnz=nnz)
     x = x_ref[:, pl.ds(k * block_k, block_k)]
@@ -220,11 +221,9 @@ def dbb_gemm_skinny_pallas(
         pl.BlockSpec((nb_tile, block_n), lambda j, kk: (kk, j)),
     ]
     if bits == 4:
-        gpt = max(block_k // group, 1)
-        gdiv = max(group // block_k, 1)
-        operands.append(gscale)
-        in_specs.append(pl.BlockSpec((gpt, block_n),
-                                     lambda j, kk: (kk // gdiv, j)))
+        operands.append(gscale)        # resident [K/G, bn] scale column
+        in_specs.append(pl.BlockSpec((k_dim // group, block_n),
+                                     lambda j, kk: (0, j)))
     row_spec = pl.BlockSpec((1, block_n), lambda j, kk: (0, j))
     if epilogue.has_bias:
         assert bias is not None and bias.shape == (1, n), (
@@ -240,7 +239,7 @@ def dbb_gemm_skinny_pallas(
     grid = (n // block_n, n_k)
     kernel = functools.partial(_dbb_skinny_kernel, n_k=n_k, block_k=block_k,
                                block=block, nnz=nnz, out_dtype=out_dtype,
-                               epilogue=epilogue, bits=bits)
+                               epilogue=epilogue, bits=bits, group=group)
     return pl.pallas_call(
         kernel,
         grid=grid,

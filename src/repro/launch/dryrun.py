@@ -1,3 +1,12 @@
+"""Dry-run compile sweep: ``python -m repro.launch.dryrun [...]``.
+
+A CPU-only tool. It forces 512 virtual host devices before JAX starts and
+lowers each (arch, shape, mesh) cell's step programs against a virtual
+production mesh, recording roofline terms per cell; ``--all`` runs one
+child process per cell. Do not run it on a machine with a TPU: one chip
+serves one process, and the cells' processes would contend for it.
+Kernel compiles for the real chip live in ``tests/test_v5e_compile.py``.
+"""
 import os
 os.environ["XLA_FLAGS"] = "--xla_force_host_platform_device_count=512"
 

@@ -18,11 +18,8 @@ MULTI_POD_SHAPE = (2, 16, 16)              # 2 pods = 512 chips
 
 
 def _mk(shape: Tuple[int, ...], axes: Tuple[str, ...]) -> Mesh:
-    if hasattr(jax.sharding, "AxisType"):   # jax >= 0.5 explicit-axes API
-        return jax.make_mesh(
-            shape, axes,
-            axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
-    return jax.make_mesh(shape, axes)
+    return jax.make_mesh(
+        shape, axes, axis_types=(jax.sharding.AxisType.Auto,) * len(axes))
 
 
 def make_production_mesh(*, multi_pod: bool = False) -> Mesh:

@@ -20,6 +20,7 @@ import numpy as np
 
 from repro.configs import get_config
 from repro.core.dbb_linear import pack_tree, tree_footprint_bytes
+from repro.launch.compile_cache import use_compile_cache
 from repro.models import registry
 from repro.serve.engine import ServeEngine
 
@@ -79,7 +80,8 @@ def _log_routes(cfg, batch: int, smax: int, packed: bool,
     page = cfg.kv_page_size or math.gcd(smax, DEFAULT_PAGE)
     route = dispatch.decode_attention_route(
         cfg, group=g, head_dim=hd,
-        itemsize=jnp.dtype(cfg.dtype).itemsize, page=page, smax=smax)
+        itemsize=jnp.dtype(cfg.dtype).itemsize, page=page, smax=smax,
+        kv_heads=cfg.num_kv_heads)
     print(f"- decode attention (G={g}, smax={smax}, page={page}): "
           f"{route}\n")
 
@@ -146,6 +148,7 @@ def main(argv=None) -> int:
                          "model, verify in one batched step (0 = off; "
                          "incompatible with top-k/top-p)")
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.weight_bits or args.quant_group:

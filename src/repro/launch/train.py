@@ -22,6 +22,7 @@ from repro.core.sparsity import dbb_schedule_nnz, tree_sparsity_report
 from repro.data.pipeline import make_pipeline
 from repro.dist import sharding as shd
 from repro.dist.mesh_ctx import use_mesh
+from repro.launch.compile_cache import use_compile_cache
 from repro.launch.mesh import make_smoke_mesh
 from repro.train import checkpoint as ckpt
 from repro.train.fault_tolerance import (PreemptionGuard, StragglerMonitor,
@@ -128,6 +129,7 @@ def main(argv=None) -> int:
                     help="none | dxm (e.g. 2x4) virtual mesh")
     ap.add_argument("--seed", type=int, default=0)
     args = ap.parse_args(argv)
+    use_compile_cache()
 
     cfg = get_config(args.arch, smoke=args.smoke)
     if args.dense:

@@ -21,11 +21,11 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
 from repro.core.dbb import DbbWeight
-from repro.dist.compat import shard_map
 from repro.dist.mesh_ctx import current_mesh, shard_tp
 from repro.kernels.attn import (DEFAULT_PAGE, identity_block_table,
                                 paged_decode_attention)
@@ -453,7 +453,7 @@ def decode_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
     page = cfg.kv_page_size or math.gcd(smax, DEFAULT_PAGE)
     decode_route = dispatch.decode_attention_route(
         cfg, group=g, head_dim=hd, itemsize=new_k.dtype.itemsize,
-        page=page, smax=smax, ring=ring,
+        page=page, smax=smax, kv_heads=hkv, ring=ring,
         floating=jnp.issubdtype(x.dtype, jnp.floating))
     if decode_route == "attn_decode_flash":
         window = (cfg.sliding_window if window_override is None
@@ -600,6 +600,16 @@ def paged_decode_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
     new_kp = k_pages.at[phys, off].set(k[:, 0].astype(k_pages.dtype))
     new_vp = v_pages.at[phys, off].set(v[:, 0].astype(v_pages.dtype))
 
+    # the pool has no other decode path: the engine offers paged serving
+    # only where the registry picks the kernel, which this asserts
+    from repro.kernels import dispatch
+    route = dispatch.decode_attention_route(
+        cfg, group=g, head_dim=hd, itemsize=k_pages.dtype.itemsize,
+        page=page, smax=n_log * page, kv_heads=hkv,
+        floating=jnp.issubdtype(x.dtype, jnp.floating))
+    if route != "attn_decode_flash":
+        raise ValueError(f"paged KV decode needs the attn_decode_flash "
+                         f"route; the registry picked {route!r}")
     window = (cfg.sliding_window if window_override is None
               else window_override)
     o = paged_decode_attention(

@@ -15,11 +15,11 @@ from typing import Dict
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
 from repro.core.dbb import DbbWeight
-from repro.dist.compat import shard_map
 from repro.dist.mesh_ctx import current_mesh, data_axes_of, shard_tp
 from repro.models.common import linear_init, use_fused_gemm
 

@@ -20,10 +20,10 @@ from typing import Dict, Optional, Tuple
 
 import jax
 import jax.numpy as jnp
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
-from repro.dist.compat import shard_map
 from repro.dist.mesh_ctx import current_mesh, data_axes_of
 from repro.models.common import linear_init, normal_init
 from repro.models.mlp import _ACTS, mlp_apply, mlp_init, seq_parallel_ok

@@ -43,12 +43,12 @@ import jax
 import jax.numpy as jnp
 import numpy as np
 
+from jax import shard_map
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
 from repro.core.dbb_linear import maybe_decompress_tree
 from repro.dist.collectives import cross_entropy  # noqa: F401 (API surface)
-from repro.dist.compat import shard_map
 from repro.dist.mesh_ctx import current_mesh, shard_tp, shard_tp_ctx
 from repro.kernels import dispatch
 from repro.models import registry
@@ -58,6 +58,7 @@ __all__ = ["make_decode_step", "make_prefill_step",
            "make_sample_decode_step", "make_spec_decode_step",
            "make_sample_prefill_step", "make_sample_packed_prefill_step",
            "make_sample_chunk_prefill_step",
+           "make_packed_logits_step",
            "ServeEngine", "greedy_from_hidden", "tp_serve_reason"]
 
 # Families whose decode cache is the attention [L, B, S, H, D] K/V layout
@@ -214,6 +215,30 @@ def make_packed_prefill_step(cfg: ModelConfig):
         nxt = greedy_from_hidden(last, registry.lm_head_weight(p, cfg),
                                  impl=_gemm_impl(cfg), cfg=cfg)
         return nxt, new_cache
+
+    return step
+
+
+def make_packed_logits_step(cfg: ModelConfig):
+    """packed_logits(params, cache, <make_packed_prefill_step args>) ->
+    (last-position logits [Gp, vocab] f32, cache): the packed prefill step
+    with its head left as logits — the correctness probe that compares
+    routes on the serving path itself. Inside a TP shard_map body the
+    vocab-parallel head's [Gp, vocab/tp] slices are all-gathered."""
+
+    def step(params, cache, tokens, seg_ids, positions, rows, cols,
+             gather_idx):
+        p = _decompress_non_layer(params, cfg)
+        hidden, new_cache = registry.prefill_packed(
+            p, cfg, tokens, seg_ids, positions, rows, cols, cache)
+        last = jnp.take(hidden[0], gather_idx, axis=0).astype(jnp.float32)
+        w_head = registry.lm_head_weight(p, cfg).astype(jnp.float32)
+        logits = dispatch.matmul(last, w_head, cfg=cfg,
+                                 pallas=_gemm_impl(cfg) == "pallas",
+                                 gemv=True)
+        if shard_tp() > 1:
+            logits = jax.lax.all_gather(logits, "model", axis=1, tiled=True)
+        return logits, new_cache
 
     return step
 
@@ -481,6 +506,37 @@ def _bucket_len(n: int, minimum: int = 8) -> int:
     return b
 
 
+def _packed_call_args(seqs: Sequence[Sequence[int]], addrs, pad_row: int
+                      ) -> Tuple[Tuple[jax.Array, ...], int]:
+    """Device args of one packed cu_seqlens prefill call (DESIGN.md §12):
+    ``seqs[i]`` are request i's tokens and ``addrs[i]`` their (rows, cols)
+    KV scatter addresses. Returns ((tokens [1, Tp], seg_ids [Tp],
+    positions [1, Tp], rows [Tp], cols [Tp], gather_idx [Gp]), Tp):
+    Tp/Gp are power-of-two bucketed, ``gather_idx`` names each request's
+    last packed position, and padding carries segment id ``len(seqs)``
+    (larger than every real id, so seg stays non-decreasing and matches
+    nothing) and scatter row ``pad_row`` (out of range: dropped)."""
+    tp = _bucket_len(sum(len(q) for q in seqs), 8)
+    toks = np.zeros((tp,), np.int32)
+    seg = np.full((tp,), len(seqs), np.int32)
+    pos = np.zeros((tp,), np.int32)
+    rows = np.full((tp,), pad_row, np.int32)
+    cols = np.zeros((tp,), np.int32)
+    gidx = np.zeros((_bucket_len(len(seqs), 1),), np.int32)
+    off = 0
+    for i, (q, (r, c)) in enumerate(zip(seqs, addrs)):
+        n = len(q)
+        toks[off:off + n] = q
+        seg[off:off + n] = i
+        pos[off:off + n] = np.arange(n)
+        rows[off:off + n], cols[off:off + n] = r, c
+        gidx[i] = off + n - 1
+        off += n
+    return (jnp.asarray(toks)[None], jnp.asarray(seg),
+            jnp.asarray(pos)[None], jnp.asarray(rows), jnp.asarray(cols),
+            jnp.asarray(gidx)), tp
+
+
 @dataclasses.dataclass
 class ServeEngine:
     """Batched greedy-decoding engine (examples + tests + benchmarks).
@@ -593,6 +649,7 @@ class ServeEngine:
         self._install = jax.jit(self._install_fn, donate_argnums=0)
         self._install_paged = jax.jit(self._install_paged_fn,
                                       donate_argnums=0)
+        self._logits = None          # prefill_logits' step, built on use
         # sampled/speculative variants, built lazily per static knob set
         # (use_tt, draft_k) — a greedy engine never traces sampling code
         self._sample_raws: Dict[Any, Any] = {}
@@ -844,6 +901,25 @@ class ServeEngine:
                     break
             outs.append(row)
         return outs
+
+    def prefill_logits(self, prompts: List[List[int]]) -> np.ndarray:
+        """Last-position logits [len(prompts), vocab] (f32, on the host)
+        of each prompt through the packed prefill path `serve` admits
+        with — same kernels, KV scatter and TP wrap, head argmax left
+        off. Compare it across ``gemm_impl`` on the same weights to check
+        a route end to end."""
+        assert 0 < len(prompts) <= self.max_batch
+        backend = _ContiguousKvBackend(
+            self, _bucket_len(max(len(q) for q in prompts)))
+        packed, _ = _packed_call_args(
+            prompts,
+            [backend.token_addr(i, (), np.arange(len(q), dtype=np.int64))
+             for i, q in enumerate(prompts)],
+            backend.pad_row())
+        if self._logits is None:
+            self._logits = jax.jit(self._tp_step(make_packed_logits_step))
+        logits, _ = self._logits(self.params, backend.init_cache(), *packed)
+        return np.asarray(logits[:len(prompts)])
 
     def _spec_mode(self, sampling: Sequence[Any],
                    draft_k: Optional[int]) -> Tuple[bool, int]:
@@ -1383,34 +1459,19 @@ class ServeEngine:
                 spent += c
             if items:
                 total = sum(c for _, _, c in items)
-                tp = _bucket_len(total, 8)
-                toks = np.zeros((tp,), np.int32)
-                # pad positions carry segment id n_items: larger than every
-                # real id (keeps seg non-decreasing), matched by nothing
-                seg = np.full((tp,), len(items), np.int32)
-                pos = np.zeros((tp,), np.int32)
-                rows = np.full((tp,), backend.pad_row(), np.int32)
-                cols = np.zeros((tp,), np.int32)
-                gidx = np.zeros((_bucket_len(len(items), 1),), np.int32)
-                off = 0
-                for i, (slot, st, c) in enumerate(items):
-                    toks[off:off + c] = prompts[st[0]][:c]
-                    seg[off:off + c] = i
-                    pos[off:off + c] = np.arange(c)
-                    rows[off:off + c], cols[off:off + c] = \
-                        backend.token_addr(slot, st[2],
-                                           np.arange(c, dtype=np.int64))
-                    gidx[i] = off + c - 1
-                    off += c
-                pargs = (self.params, cache, jnp.asarray(toks)[None],
-                         jnp.asarray(seg), jnp.asarray(pos)[None],
-                         jnp.asarray(rows), jnp.asarray(cols),
-                         jnp.asarray(gidx))
+                packed, tp = _packed_call_args(
+                    [prompts[st[0]][:c] for _, st, c in items],
+                    [backend.token_addr(slot, st[2],
+                                        np.arange(c, dtype=np.int64))
+                     for slot, st, c in items],
+                    backend.pad_row())
+                pargs = (self.params, cache) + packed
                 if sampled:
-                    fvp = np.zeros((gidx.shape[0], 5), np.float32)
+                    gp = packed[-1].shape[0]
+                    fvp = np.zeros((gp, 5), np.float32)
                     fvp[:, 1] = 1.0                  # spare rows: identity
                     fvp[:, 2] = 1.0
-                    ivp = np.zeros((gidx.shape[0], 2), np.int32)
+                    ivp = np.zeros((gp, 2), np.int32)
                     for i, (slot, st, c) in enumerate(items):
                         f, ivv = pack_params(sampling[st[0]])
                         fvp[i], ivp[i] = np.asarray(f), np.asarray(ivv)
@@ -1577,7 +1638,8 @@ class _PagedKvBackend:
             raise ValueError(
                 f"kv_page_size={self.page} below the minimum page of 8 "
                 "slots (sublane quantum)")
-        if not paged_decode_ok(self.page, cfg.resolved_head_dim,
+        if not paged_decode_ok(self.page, cfg.num_kv_heads,
+                               cfg.resolved_head_dim,
                                jnp.dtype(cfg.dtype).itemsize):
             raise ValueError(
                 f"kv_page_size={self.page} makes a KV page tile that "

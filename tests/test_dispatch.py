@@ -360,6 +360,22 @@ class TestOverrides:
 # model-layer integration
 # ---------------------------------------------------------------------------
 
+class TestRecordRoutes:
+    def test_records_front_door_picks_only_inside_block(self):
+        """`record_routes` collects the (domain, route) pairs the front
+        doors pick while it is open; explain() tables and calls outside
+        the block add nothing."""
+        x = jnp.ones((8, 256), jnp.float32)
+        w = jnp.ones((256, 128), jnp.float32)
+        dispatch.matmul(x, w, pallas=True)
+        with dispatch.record_routes() as log:
+            dispatch.matmul(x, w, pallas=True)
+            dispatch.matmul(x, w, pallas=False)
+            dispatch.explain("matmul", m=512, k=256, n=128, pallas=True)
+        dispatch.matmul(x, w, pallas=True)
+        assert log == {("matmul", "skinny_sta"), ("matmul", "xla")}
+
+
 class TestModelLayerIntegration:
     def test_kernel_routes_thread_through_model(self):
         """A config-pinned xla route changes nothing numerically for the

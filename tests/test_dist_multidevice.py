@@ -171,7 +171,7 @@ def test_tp_gemm_bit_exact_matrix():
     out = _run("""
 import dataclasses
 from repro.core.dbb import pack_dbb
-from repro.dist.compat import shard_map
+from jax import shard_map
 from repro.dist.mesh_ctx import shard_tp_ctx, use_mesh
 from repro.kernels import dispatch
 from repro.launch.mesh import make_smoke_mesh

@@ -205,6 +205,28 @@ class TestServeParity:
         assert all(len(t) == b for t, b in
                    zip(eng.serve(prompts, budgets), budgets))
 
+    @pytest.mark.parametrize("paged", [False, True])
+    def test_prefill_logits_match_routes_and_forward(self, paged):
+        """`prefill_logits` (the packed prefill path with the head left as
+        logits) agrees across the Pallas and XLA routes and with each
+        prompt's solo full forward, on both engine configs of this file —
+        the correctness probe the chip smoke test relies on."""
+        eng = _engine(paged)
+        cfg = eng.cfg.replace(gemm_impl="pallas")
+        params = registry.init_params(jax.random.PRNGKey(0), cfg)
+        prompts = [[5, 17, 3, 250, 99], [7, 12], [2, 9, 31, 44, 8, 61, 3]]
+        pal = ServeEngine(cfg, params, max_batch=4).prefill_logits(prompts)
+        xla = ServeEngine(cfg.replace(gemm_impl="xla"), params,
+                          max_batch=4).prefill_logits(prompts)
+        assert pal.shape == (len(prompts), cfg.vocab_size)
+        np.testing.assert_allclose(pal, xla, rtol=1e-4, atol=1e-4)
+        w = registry.lm_head_weight(params, cfg).astype(jnp.float32)
+        for i, p in enumerate(prompts):
+            h, _ = registry.forward(params, cfg.replace(gemm_impl="xla"),
+                                    {"tokens": jnp.asarray([p], jnp.int32)})
+            want = np.asarray(h[0, -1].astype(jnp.float32) @ w)
+            np.testing.assert_allclose(xla[i], want, rtol=1e-4, atol=1e-4)
+
     def test_ttft_recorded(self):
         """serve_stats carries a TTFT sample per request (used by the
         packed-prefill benchmark's jitter sweep)."""

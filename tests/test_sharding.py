@@ -143,3 +143,20 @@ def test_zero_spec_adds_data_axes():
     assert "data" in tuple(spec)
     # small leaves untouched
     assert tuple(shd.zero_spec(P(), (64,), POD)) == ()
+
+
+def test_use_mesh_takes_explicit_axes_as_auto():
+    """`jax.make_mesh` builds Explicit axes by default; the model code
+    shards with constraints and shard_map, so `use_mesh` serves the same
+    devices and axis names with Auto axes."""
+    from jax.sharding import AxisType
+
+    from repro.dist.mesh_ctx import current_mesh, use_mesh
+    mesh = jax.make_mesh((1, 1), ("data", "model"),
+                         axis_types=(AxisType.Explicit,) * 2)
+    with use_mesh(mesh) as m:
+        assert current_mesh() is m
+        assert m.axis_types == (AxisType.Auto, AxisType.Auto)
+        assert m.axis_names == mesh.axis_names
+        assert (m.devices == mesh.devices).all()
+    assert current_mesh() is None

@@ -278,3 +278,21 @@ def test_retry_step_exhausts():
     with pytest.raises(RuntimeError):
         retry_step(lambda: (_ for _ in ()).throw(RuntimeError("x")),
                    retries=1, backoff_s=0.0)
+
+
+def test_compile_cache_location(monkeypatch, tmp_path):
+    """Entry points keep JAX's persistent compile cache where
+    JAX_COMPILATION_CACHE_DIR says (setting nothing themselves), else at
+    <checkout>/.jax_cache."""
+    from repro.launch import compile_cache
+    calls = []
+    monkeypatch.setattr(compile_cache.jax.config, "update",
+                        lambda name, value: calls.append((name, value)))
+    monkeypatch.setenv("JAX_COMPILATION_CACHE_DIR", str(tmp_path))
+    assert compile_cache.use_compile_cache() == str(tmp_path)
+    assert calls == []
+    monkeypatch.delenv("JAX_COMPILATION_CACHE_DIR")
+    checkout = os.path.dirname(os.path.dirname(os.path.abspath(__file__)))
+    want = os.path.join(checkout, ".jax_cache")
+    assert compile_cache.use_compile_cache() == want
+    assert calls == [("jax_compilation_cache_dir", want)]
