@@ -190,6 +190,7 @@ def flash_prefill_pallas(
     )
     return pl.pallas_call(
         kernel,
+        name="flash_prefill",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hq, t, d), q.dtype),
         compiler_params=CompilerParams(
@@ -311,6 +312,7 @@ def flash_prefill_packed_pallas(
         softcap=softcap, out_dtype=q.dtype)
     return pl.pallas_call(
         kernel,
+        name="flash_prefill_packed",
         grid=(hq, n_q, n_kv),
         in_specs=[
             pl.BlockSpec((1, block_q, d), lambda h, i, j: (h, i, 0)),
@@ -433,6 +435,7 @@ def paged_decode_pallas(
     )
     return pl.pallas_call(
         kernel,
+        name="paged_decode",
         grid_spec=grid_spec,
         out_shape=jax.ShapeDtypeStruct((b, hkv, g, d), q.dtype),
         compiler_params=CompilerParams(

@@ -213,6 +213,7 @@ def conv_gemm_pallas(
         out_dtype=out_dtype, epilogue=epilogue)
     return pl.pallas_call(
         kernel,
+        name="conv_gemm",
         grid=grid,
         in_specs=[x_spec, w_spec] + extra_specs,
         out_specs=out_spec,
@@ -280,6 +281,7 @@ def conv_gemm_dbb_pallas(
         block=block, nnz=nnz, out_dtype=out_dtype, epilogue=epilogue)
     return pl.pallas_call(
         kernel,
+        name="conv_gemm_dbb",
         grid=grid,
         in_specs=[x_spec, v_spec, m_spec] + extra_specs,
         out_specs=out_spec,

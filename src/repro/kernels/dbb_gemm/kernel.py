@@ -214,6 +214,7 @@ def dbb_gemm_pallas(
                                epilogue=epilogue, bits=bits, group=group)
     return pl.pallas_call(
         kernel,
+        name="dbb_gemm_tiled",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),

@@ -117,6 +117,7 @@ def head_sample_fused_pallas(
                                block_k=block_k, block_n=block_n)
     return pl.pallas_call(
         kernel,
+        name="head_sample_fused",
         grid=(n // block_n, n_k),
         in_specs=[
             pl.BlockSpec((m, k), lambda j, kk: (0, 0)),      # resident x

@@ -123,6 +123,7 @@ def sta_gemm_skinny_pallas(
                                out_dtype=out_dtype, epilogue=epilogue)
     return pl.pallas_call(
         kernel,
+        name="sta_gemm_skinny",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((m, block_n), lambda j, kk: (0, j)),
@@ -242,6 +243,7 @@ def dbb_gemm_skinny_pallas(
                                epilogue=epilogue, bits=bits, group=group)
     return pl.pallas_call(
         kernel,
+        name="dbb_gemm_skinny",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((m, block_n), lambda j, kk: (0, j)),

@@ -109,6 +109,7 @@ def sta_gemm_pallas(
                                epilogue=epilogue)
     return pl.pallas_call(
         kernel,
+        name="sta_gemm_tiled",
         grid=grid,
         in_specs=in_specs,
         out_specs=pl.BlockSpec((block_m, block_n), lambda i, j, kk: (i, j)),

@@ -36,6 +36,7 @@ input-shape cells.
 from __future__ import annotations
 
 import dataclasses
+import time
 from collections import deque
 from typing import Any, Dict, List, Optional, Sequence, Tuple, Union
 
@@ -44,6 +45,7 @@ import jax.numpy as jnp
 import numpy as np
 
 from jax import shard_map
+from jax.profiler import StepTraceAnnotation, TraceAnnotation
 from jax.sharding import PartitionSpec as P
 
 from repro.config import ModelConfig
@@ -206,6 +208,7 @@ def make_packed_prefill_step(cfg: ModelConfig):
     row and is dropped — and ``gather_idx`` names each request's last
     packed position, whose hidden state feeds the greedy head."""
 
+    @jax.named_scope("prefill_packed")
     def step(params, cache, tokens, seg_ids, positions, rows, cols,
              gather_idx):
         p = _decompress_non_layer(params, cfg)
@@ -253,6 +256,7 @@ def make_chunk_prefill_step(cfg: ModelConfig):
     token from the chunk's last real position (only consumed when this
     chunk completes the prompt)."""
 
+    @jax.named_scope("prefill_continue")
     def step(params, cache, tokens, positions, rows, cols, kv_sel,
              last_idx):
         p = _decompress_non_layer(params, cfg)
@@ -417,6 +421,7 @@ def make_sample_packed_prefill_step(cfg: ModelConfig,
     zero history is a plain argmax, and their tokens are never consumed."""
     from repro.serve import sampling
 
+    @jax.named_scope("prefill_packed")
     def step(params, cache, tokens, seg_ids, positions, rows, cols,
              gather_idx, fvals, ivals):
         p = _decompress_non_layer(params, cfg)
@@ -442,6 +447,7 @@ def make_sample_chunk_prefill_step(cfg: ModelConfig,
     request's FIRST emitted token, drawn at RNG ordinal 0."""
     from repro.serve import sampling
 
+    @jax.named_scope("prefill_continue")
     def step(params, cache, tokens, positions, rows, cols, kv_sel,
              last_idx, fvals, ivals):
         p = _decompress_non_layer(params, cfg)
@@ -461,7 +467,7 @@ def make_sample_chunk_prefill_step(cfg: ModelConfig,
 
 def _consume_slot(host_emit: np.ndarray, host_nem: np.ndarray, slot: int,
                   row: List[int], left: int, eos_id: int
-                  ) -> Tuple[int, bool]:
+                  ) -> Tuple[int, bool, int]:
     """Drain one slot's emitted tokens from a fetched chunk into ``row``.
 
     ``host_emit`` [steps, B, ke] / ``host_nem`` [steps, B]: per decode
@@ -470,15 +476,16 @@ def _consume_slot(host_emit: np.ndarray, host_nem: np.ndarray, slot: int,
     1..k+1; plain steps always 1). Consumption stops at EOS or when the
     request's remaining ``left`` budget hits zero — surplus tokens from
     overshoot steps are discarded, exactly like the greedy loops.
-    Returns (remaining budget, finished)."""
+    Returns (remaining budget, finished, steps the row consumed tokens
+    from); the chunk's later steps are the row's surplus."""
     for s in range(host_emit.shape[0]):
         for j in range(int(host_nem[s, slot])):
             t = int(host_emit[s, slot, j])
             row.append(t)
             left -= 1
             if t == eos_id or left <= 0:
-                return left, True
-    return left, False
+                return left, True, s + 1
+    return left, False, host_emit.shape[0]
 
 
 def _bump_spec_stats(stats: Dict[str, int], host_n: np.ndarray,
@@ -493,6 +500,47 @@ def _bump_spec_stats(stats: Dict[str, int], host_n: np.ndarray,
     stats["spec_emitted"] = (stats.get("spec_emitted", 0)
                              + sum(int(host_n[:, s].sum())
                                    for s in active))
+
+
+@dataclasses.dataclass
+class _ServeCall:
+    """State of one ``serve()`` call that both scheduler loops share: the
+    device-resident decode batch (cache, current tokens, done mask,
+    sampling state), the slot bookkeeping, the counters that become
+    ``serve_stats``, and each request's timeline in seconds from ``t0``,
+    the loop's start: slot assigned (``assign_s``), first token on the
+    host (``ttft_s``), last token consumed (``done_s``)."""
+    t0: float
+    cache: Any
+    cur: jax.Array
+    done: jax.Array
+    sstate: Any                  # None unless the call samples
+    outs: List[List[int]]
+    free: List[int]              # free slots
+    stats: Dict[str, Any]
+    active: Dict[int, int] = dataclasses.field(
+        default_factory=dict)    # slot -> request idx
+    left: Dict[int, int] = dataclasses.field(
+        default_factory=dict)    # request idx -> budget
+    assign_s: Dict[int, float] = dataclasses.field(default_factory=dict)
+    ttft_s: Dict[int, float] = dataclasses.field(default_factory=dict)
+    done_s: Dict[int, float] = dataclasses.field(default_factory=dict)
+
+    def __post_init__(self):
+        self.stats.update(decode_steps=0, decode_row_steps=0,
+                          decode_surplus_row_steps=0)
+
+    def stamp(self, times: Dict[int, float], ridx: int) -> None:
+        times[ridx] = time.perf_counter() - self.t0
+
+    def finish(self) -> Dict[str, Any]:
+        """``stats`` with the timeline, one entry per request (nan where a
+        request never got that far)."""
+        for key in ("assign_s", "ttft_s", "done_s"):
+            times = getattr(self, key)
+            self.stats[key] = [times.get(i, float("nan"))
+                               for i in range(len(self.outs))]
+        return self.stats
 
 
 def _bucket_len(n: int, minimum: int = 8) -> int:
@@ -715,6 +763,7 @@ class ServeEngine:
         if fn is None:
             raw, eos = self._decode_raw, self.eos_id
 
+            @jax.named_scope("decode_chunk")
             def chunk(params, cache, cur, done):
                 def live(carry):
                     cur, cache, done = carry
@@ -791,6 +840,7 @@ class ServeEngine:
             eos, ke = self.eos_id, draft_k + 1
             spec = draft_k > 0
 
+            @jax.named_scope("decode_chunk")
             def chunk(params, cache, cur, done, sstate):
                 def live(carry):
                     cur, cache, done, sstate = carry
@@ -1167,14 +1217,15 @@ class ServeEngine:
                    else _ContiguousKvBackend(self, smax))
         mode = prefill_mode if prefill_mode is not None else self.prefill_mode
         assert mode in ("packed", "padded"), mode
-        if mode == "packed":
-            pchunk = (prefill_chunk if prefill_chunk is not None
-                      else self.prefill_chunk)
-            return self._serve_loop_packed(prompts, budgets, blens, smax,
-                                           chunk, backend, pchunk,
-                                           sampling, use_tt, dk)
-        return self._serve_loop(prompts, budgets, blens, smax, chunk,
-                                backend, sampling, use_tt, dk)
+        with TraceAnnotation("serve.call", requests=n_req):
+            if mode == "packed":
+                pchunk = (prefill_chunk if prefill_chunk is not None
+                          else self.prefill_chunk)
+                return self._serve_loop_packed(prompts, budgets, blens,
+                                               smax, chunk, backend, pchunk,
+                                               sampling, use_tt, dk)
+            return self._serve_loop(prompts, budgets, blens, smax, chunk,
+                                    backend, sampling, use_tt, dk)
 
     def _serve_loop(self, prompts: List[List[int]], budgets: List[int],
                     blens: List[int], smax: int, chunk: int, backend,
@@ -1183,36 +1234,37 @@ class ServeEngine:
         """The one continuous-batching scheduler both KV layouts share.
         The backend only decides how cache space is reserved and where
         admissions scatter (contiguous slots vs allocated pages) — token
-        accounting, chunk decode, and retirement live here once, so the
-        two layouts cannot drift apart (their token streams are asserted
+        accounting, chunk decode, and retirement live once
+        (`_decode_chunk`, shared with `_serve_loop_packed`), so the two
+        layouts cannot drift apart (their token streams are asserted
         bit-identical, DESIGN.md §10). With ``sampling`` the decode chunks
         carry the device-resident sampling state (and, with ``dk > 0``,
         run speculative steps emitting 1..k+1 tokens each)."""
+        t0 = time.perf_counter()
         sampled = sampling is not None
         dmargin = dk + 1 if dk else 0
-        cache = backend.init_cache()
-        cur = jnp.zeros((self.max_batch,), jnp.int32)
-        done = jnp.ones((self.max_batch,), bool)
         sstate = None
         if sampled:
             from repro.serve.sampling import pack_params, sampling_state
             sstate = sampling_state(self.max_batch, self.cfg.vocab_size)
-        outs: List[List[int]] = [[] for _ in prompts]
+        call = _ServeCall(
+            t0=t0, cache=backend.init_cache(),
+            cur=jnp.zeros((self.max_batch,), jnp.int32),
+            done=jnp.ones((self.max_batch,), bool), sstate=sstate,
+            outs=[[] for _ in prompts], free=list(range(self.max_batch)),
+            stats=backend.stats)
         queue = deque(range(len(prompts)))
-        free = list(range(self.max_batch))
-        active: Dict[int, int] = {}                  # slot -> request idx
-        left: Dict[int, int] = {}                    # request idx -> budget
 
         # one reusable zero cache for every admission prefill (the jitted
         # prefill never donates it, so the template stays pristine)
         c1_template = registry.init_cache(self.cfg, 1, smax)
 
         def admit(slot: int, ridx: int):
-            nonlocal cache, cur, done, sstate
             grant = backend.reserve(ridx, blens[ridx],
                                     budgets[ridx] + dmargin)
             if grant is None:
                 return "defer"                       # wait for retirements
+            call.stamp(call.assign_s, ridx)
             p, bl = prompts[ridx], blens[ridx]
             toks = np.zeros((1, bl), np.int32)
             toks[0, bl - len(p):] = p                # left-pad to bucket
@@ -1226,76 +1278,109 @@ class ServeEngine:
             else:
                 nxt1, c1 = self._prefill(self.params, c1_template, batch1)
             tok = int(jax.device_get(nxt1)[0])       # first generated token
-            outs[ridx].append(tok)
+            call.outs[ridx].append(tok)
+            call.stamp(call.ttft_s, ridx)
             if tok == self.eos_id or budgets[ridx] <= 1:
+                call.done_s[ridx] = call.ttft_s[ridx]
                 backend.release(grant)
                 return False                         # finished at prefill
-            cache, cur, done = backend.admit(cache, c1, cur, done, slot,
-                                             nxt1[0], grant)
+            call.cache, call.cur, call.done = backend.admit(
+                call.cache, c1, call.cur, call.done, slot, nxt1[0], grant)
             if sampled:
-                sstate = self._sstate_admit(sstate, jnp.int32(slot), fv,
-                                            iv, nxt1[0])
-            active[slot] = ridx
-            left[ridx] = budgets[ridx] - 1
+                call.sstate = self._sstate_admit(
+                    call.sstate, jnp.int32(slot), fv, iv, nxt1[0])
+            call.active[slot] = ridx
+            call.left[ridx] = budgets[ridx] - 1
             return True
 
-        while queue or active:
-            # first-fit admission between decode chunks: a request whose
-            # reservation doesn't fit yet is skipped (kept in arrival
-            # order), not head-of-line blocking — short requests backfill
-            # slots behind a deferred long one. The contiguous backend
-            # always grants, which degenerates to plain FIFO fill.
-            skipped: List[int] = []
-            while queue and free:
-                ridx = queue.popleft()
-                if budgets[ridx] <= 0:
+        it = 0
+        while queue or call.active:
+            with StepTraceAnnotation("serve.iter", step_num=it):
+                it += 1
+                # first-fit admission between decode chunks: a request whose
+                # reservation doesn't fit yet is skipped (kept in arrival
+                # order), not head-of-line blocking — short requests backfill
+                # slots behind a deferred long one. The contiguous backend
+                # always grants, which degenerates to plain FIFO fill.
+                skipped: List[int] = []
+                while queue and call.free:
+                    ridx = queue.popleft()
+                    if budgets[ridx] <= 0:
+                        continue
+                    slot = call.free.pop()
+                    r = admit(slot, ridx)
+                    if r == "defer":
+                        call.free.append(slot)
+                        skipped.append(ridx)
+                        backend.stats["deferred_admissions"] += 1
+                        continue
+                    if not r:
+                        call.free.append(slot)
+                queue.extendleft(reversed(skipped))
+                if not call.active:
+                    if queue:  # deferred with nothing left to retire
+                        backend.starved(queue[0], blens, budgets)
                     continue
-                slot = free.pop()
-                r = admit(slot, ridx)
-                if r == "defer":
-                    free.append(slot)
-                    skipped.append(ridx)
-                    backend.stats["deferred_admissions"] += 1
-                    continue
-                if not r:
-                    free.append(slot)
-            queue.extendleft(reversed(skipped))
-            if not active:
-                if queue:        # deferred with nothing left to retire
-                    backend.starved(queue[0], blens, budgets)
-                continue
-            backend.stats["peak_active"] = max(
-                backend.stats["peak_active"], len(active))
-            # fixed-size chunks (one compiled scan); rows that hit EOS or
-            # their budget mid-chunk have their surplus tokens discarded
-            # below and retire at the chunk boundary
+                self._decode_chunk(call, chunk, backend, use_tt, dk,
+                                   park=None)
+        self.serve_stats = call.finish()
+        return call.outs
+
+    def _decode_chunk(self, call: _ServeCall, chunk: int, backend,
+                      use_tt: bool, dk: int, park: Optional[int]) -> None:
+        """One decode chunk over the whole batch, then token accounting
+        and retirement: the one copy both serve loops run. Chunks have a
+        fixed size (one compiled scan); rows that hit EOS or their budget
+        mid-chunk have their surplus tokens discarded and retire at the
+        chunk boundary. ``park``: the write cursor a retired contiguous
+        row goes back to (see `_serve_loop_packed`); None leaves it.
+
+        Counts, in ``call.stats``: ``decode_steps``, the steps dispatched
+        (each steps every row); ``decode_row_steps``, the steps from which
+        a live row consumed tokens (one each, but for speculative steps);
+        ``decode_surplus_row_steps``, the steps a row ran after its EOS or
+        budget inside the chunk, whose tokens are discarded."""
+        stats = call.stats
+        sampled = call.sstate is not None
+        stats["peak_active"] = max(stats["peak_active"], len(call.active))
+        with TraceAnnotation("serve.host.dispatch_decode"):
             if sampled:
-                cur, cache, done, sstate, e_d, n_d = self._sample_chunk_fn(
-                    chunk, use_tt, dk)(self.params, cache, cur, done,
-                                       sstate)
-                host_e = np.asarray(e_d)             # one fetch per chunk
-                host_n = np.asarray(n_d)
+                (call.cur, call.cache, call.done, call.sstate, e_d,
+                 n_d) = self._sample_chunk_fn(chunk, use_tt, dk)(
+                    self.params, call.cache, call.cur, call.done,
+                    call.sstate)
             else:
-                cur, cache, done, toks_d = self._chunk_fn(chunk)(
-                    self.params, cache, cur, done)
+                call.cur, call.cache, call.done, toks_d = self._chunk_fn(
+                    chunk)(self.params, call.cache, call.cur, call.done)
+        stats["decode_steps"] += chunk
+        with TraceAnnotation("serve.sync.decode"):    # one fetch per chunk
+            if sampled:
+                host_e, host_n = np.asarray(e_d), np.asarray(n_d)
+            else:
                 host_e = np.asarray(toks_d)[:, :, None]
+        with TraceAnnotation("serve.host.retire"):
+            if not sampled:
                 host_n = np.ones(host_e.shape[:2], np.int64)
             if dk:
-                _bump_spec_stats(backend.stats, host_n, active)
+                _bump_spec_stats(stats, host_n, call.active)
             retired = []
-            for slot, ridx in active.items():
-                left[ridx], fin = _consume_slot(host_e, host_n, slot,
-                                                outs[ridx], left[ridx],
-                                                self.eos_id)
+            for slot, ridx in call.active.items():
+                call.left[ridx], fin, used = _consume_slot(
+                    host_e, host_n, slot, call.outs[ridx], call.left[ridx],
+                    self.eos_id)
+                stats["decode_row_steps"] += used
                 if fin:
+                    stats["decode_surplus_row_steps"] += chunk - used
+                    call.stamp(call.done_s, ridx)
                     retired.append(slot)
             for slot in retired:
-                del active[slot]
-                free.append(slot)
-                done = done.at[slot].set(True)
-                cache = backend.retire(cache, slot)
-        self.serve_stats = backend.stats
-        return outs
+                del call.active[slot]
+                call.free.append(slot)
+                call.done = call.done.at[slot].set(True)
+                call.cache = backend.retire(call.cache, slot)
+                if park is not None:
+                    call.cache = dict(call.cache, length=call.cache[
+                        "length"].at[slot].set(park))
 
     def _serve_loop_packed(self, prompts: List[List[int]],
                            budgets: List[int], blens: List[int], smax: int,
@@ -1325,8 +1410,11 @@ class ServeEngine:
         (clamped writes land in slot smax-1, which chunk prefill never
         addresses and a live row always real-overwrites before attending);
         paged rows write through a block table still pointing at the
-        reserved dummy page."""
-        import time
+        reserved dummy page.
+
+        Each iteration runs under a ``serve.iter`` profiler span, its host
+        work under ``serve.host.*`` and its waits on the device under
+        ``serve.sync.*`` (no-ops unless a profiler runs)."""
         t0 = time.perf_counter()
         sampled = sampling is not None
         dmargin = dk + 1 if dk else 0
@@ -1335,137 +1423,95 @@ class ServeEngine:
         if not paged:
             cache = dict(cache, length=jnp.full((self.max_batch,), smax,
                                                 jnp.int32))
-        cur = jnp.zeros((self.max_batch,), jnp.int32)
-        done = jnp.ones((self.max_batch,), bool)
         sstate = None
         if sampled:
             from repro.serve.sampling import pack_params, sampling_state
             sstate = sampling_state(self.max_batch, self.cfg.vocab_size)
-        outs: List[List[int]] = [[] for _ in prompts]
+        call = _ServeCall(
+            t0=t0, cache=cache, cur=jnp.zeros((self.max_batch,), jnp.int32),
+            done=jnp.ones((self.max_batch,), bool), sstate=sstate,
+            outs=[[] for _ in prompts], free=list(range(self.max_batch)),
+            stats=backend.stats)
         queue = deque(range(len(prompts)))
-        free = list(range(self.max_batch))
-        active: Dict[int, int] = {}                  # slot -> request idx
-        left: Dict[int, int] = {}                    # request idx -> budget
         # slot -> [ridx, prefilled_offset, grant] (insertion order = FIFO)
         pending: Dict[int, list] = {}
-        stats = backend.stats
-        stats.update(prefill_calls=0, packed_prefill_tokens=0,
-                     prompt_tokens=0, max_prefill_call_tokens=0,
-                     prefill_iters=0)
-        ttft: Dict[int, float] = {}
+        stats = call.stats
+        stats.update(packed_prefill_tokens=0, prompt_tokens=0,
+                     max_prefill_call_tokens=0)
 
         def bump(tokens_padded: int, tokens_real: int):
-            stats["prefill_calls"] += 1
             stats["packed_prefill_tokens"] += tokens_padded
             stats["prompt_tokens"] += tokens_real
             stats["max_prefill_call_tokens"] = max(
                 stats["max_prefill_call_tokens"], tokens_padded)
 
         def complete(slot: int, st: list, tok: int):
-            nonlocal cache, cur, done, sstate
             ridx, grant = st[0], st[2]
-            outs[ridx].append(tok)
-            ttft[ridx] = time.perf_counter() - t0
-            del pending[slot]
-            if tok == self.eos_id or budgets[ridx] <= 1:
-                backend.release(grant)
-                free.append(slot)
-                return
-            cache, cur, done = backend.install(
-                cache, cur, done, slot, jnp.int32(tok),
-                len(prompts[ridx]), grant)
-            if sampled:
-                fv, iv = pack_params(sampling[ridx])
-                sstate = self._sstate_admit(sstate, jnp.int32(slot), fv,
-                                            iv, jnp.int32(tok))
-            active[slot] = ridx
-            left[ridx] = budgets[ridx] - 1
+            with TraceAnnotation("serve.host.install", ridx=ridx):
+                call.outs[ridx].append(tok)
+                call.stamp(call.ttft_s, ridx)
+                del pending[slot]
+                if tok == self.eos_id or budgets[ridx] <= 1:
+                    call.done_s[ridx] = call.ttft_s[ridx]
+                    backend.release(grant)
+                    call.free.append(slot)
+                    return
+                call.cache, call.cur, call.done = backend.install(
+                    call.cache, call.cur, call.done, slot, jnp.int32(tok),
+                    len(prompts[ridx]), grant)
+                if sampled:
+                    fv, iv = pack_params(sampling[ridx])
+                    call.sstate = self._sstate_admit(
+                        call.sstate, jnp.int32(slot), fv, iv,
+                        jnp.int32(tok))
+                call.active[slot] = ridx
+                call.left[ridx] = budgets[ridx] - 1
 
         def run_continue(slot: int, st: list) -> int:
-            nonlocal cache
             ridx, off = st[0], st[1]
             p = prompts[ridx]
             c = (min(len(p) - off, prefill_chunk) if prefill_chunk > 0
                  else len(p) - off)
             cp = _bucket_len(c, 8)
-            toks = np.zeros((1, cp), np.int32)
-            toks[0, :c] = p[off:off + c]
-            pos = off + np.arange(cp, dtype=np.int32)
-            rows = np.full((cp,), backend.pad_row(), np.int32)
-            cols = np.zeros((cp,), np.int32)
-            rows[:c], cols[:c] = backend.token_addr(
-                slot, st[2], np.arange(off, off + c, dtype=np.int64))
-            cargs = (self.params, cache, jnp.asarray(toks),
-                     jnp.asarray(pos)[None], jnp.asarray(rows),
-                     jnp.asarray(cols), backend.kv_sel(slot, st[2]),
-                     jnp.int32(c - 1))
-            if sampled:
-                fv, iv = pack_params(sampling[ridx])
-                (nxt, _), cache = self._sample_prefill_fn("chunk", use_tt)(
-                    *cargs, fv[None], iv[None])
-            else:
-                nxt, cache = self._prefill_continue(*cargs)
+            with TraceAnnotation("serve.host.pack"):
+                toks = np.zeros((1, cp), np.int32)
+                toks[0, :c] = p[off:off + c]
+                pos = off + np.arange(cp, dtype=np.int32)
+                rows = np.full((cp,), backend.pad_row(), np.int32)
+                cols = np.zeros((cp,), np.int32)
+                rows[:c], cols[:c] = backend.token_addr(
+                    slot, st[2], np.arange(off, off + c, dtype=np.int64))
+                cargs = (jnp.asarray(toks), jnp.asarray(pos)[None],
+                         jnp.asarray(rows), jnp.asarray(cols),
+                         backend.kv_sel(slot, st[2]), jnp.int32(c - 1))
+                if sampled:
+                    fv, iv = pack_params(sampling[ridx])
+            with TraceAnnotation("serve.host.dispatch_continue", ridx=ridx):
+                if sampled:
+                    (nxt, _), call.cache = self._sample_prefill_fn(
+                        "chunk", use_tt)(self.params, call.cache, *cargs,
+                                         fv[None], iv[None])
+                else:
+                    nxt, call.cache = self._prefill_continue(
+                        self.params, call.cache, *cargs)
             st[1] = off + c
             bump(cp, c)
             if st[1] == len(p):
-                complete(slot, st, int(jax.device_get(nxt)[0]))
+                with TraceAnnotation("serve.sync.first_token"):
+                    tok = int(jax.device_get(nxt)[0])
+                complete(slot, st, tok)
             return c
 
-        while queue or pending or active:
-            # 1) slot assignment: reservation only, arrival order; a
-            # deferred reservation (paged pool exhausted) is skipped, not
-            # head-of-line blocking
-            skipped: List[int] = []
-            while queue and free:
-                ridx = queue.popleft()
-                if budgets[ridx] <= 0:
-                    continue
-                grant = backend.reserve(ridx, len(prompts[ridx]),
-                                        budgets[ridx] + dmargin)
-                if grant is None:
-                    skipped.append(ridx)
-                    stats["deferred_admissions"] += 1
-                    continue
-                pending[free.pop()] = [ridx, 0, grant]
-            queue.extendleft(reversed(skipped))
-            if not pending and not active:
-                if queue:        # deferred with nothing left to retire
-                    backend.starved(queue[0], blens, budgets)
-                continue
-
-            # 2) prefill: ≤ prefill_chunk prompt tokens this iteration
-            # (always ≥ one chunk of progress when anything is pending) —
-            # continuations first, then the packed first-chunk call
-            budget = prefill_chunk if prefill_chunk > 0 else float("inf")
-            spent = 0
-            if pending:
-                stats["prefill_iters"] += 1
-            for slot, st in list(pending.items()):
-                if st[1] == 0:
-                    continue
-                if spent >= budget:
-                    break
-                spent += run_continue(slot, st)
-            items = []
-            for slot, st in list(pending.items()):
-                if st[1] != 0:
-                    continue
-                length = len(prompts[st[0]])
-                c = (min(length, prefill_chunk) if prefill_chunk > 0
-                     else length)
-                if (spent > 0 or items) and spent + c > budget:
-                    break
-                items.append((slot, st, c))
-                spent += c
-            if items:
-                total = sum(c for _, _, c in items)
+        def run_packed(items: List[tuple]) -> None:
+            """One packed call over the first chunks of ``items``, each
+            (slot, pending entry, chunk length)."""
+            with TraceAnnotation("serve.host.pack"):
                 packed, tp = _packed_call_args(
                     [prompts[st[0]][:c] for _, st, c in items],
                     [backend.token_addr(slot, st[2],
                                         np.arange(c, dtype=np.int64))
                      for slot, st, c in items],
                     backend.pad_row())
-                pargs = (self.params, cache) + packed
                 if sampled:
                     gp = packed[-1].shape[0]
                     fvp = np.zeros((gp, 5), np.float32)
@@ -1475,58 +1521,83 @@ class ServeEngine:
                     for i, (slot, st, c) in enumerate(items):
                         f, ivv = pack_params(sampling[st[0]])
                         fvp[i], ivp[i] = np.asarray(f), np.asarray(ivv)
-                    (nxt, _), cache = self._sample_prefill_fn(
-                        "packed", use_tt)(*pargs, jnp.asarray(fvp),
-                                          jnp.asarray(ivp))
+                    extra = (jnp.asarray(fvp), jnp.asarray(ivp))
+            with TraceAnnotation("serve.host.dispatch_prefill"):
+                if sampled:
+                    (nxt, _), call.cache = self._sample_prefill_fn(
+                        "packed", use_tt)(self.params, call.cache, *packed,
+                                          *extra)
                 else:
-                    nxt, cache = self._packed_prefill(*pargs)
-                bump(tp, total)
-                host_tok = None
-                for i, (slot, st, c) in enumerate(items):
-                    st[1] = c
-                    if c == len(prompts[st[0]]):
-                        if host_tok is None:     # one sync per packed call
+                    nxt, call.cache = self._packed_prefill(
+                        self.params, call.cache, *packed)
+            bump(tp, sum(c for _, _, c in items))
+            host_tok = None
+            for i, (slot, st, c) in enumerate(items):
+                st[1] = c
+                if c == len(prompts[st[0]]):
+                    if host_tok is None:         # one sync per packed call
+                        with TraceAnnotation("serve.sync.first_token"):
                             host_tok = np.asarray(jax.device_get(nxt))
-                        complete(slot, st, int(host_tok[i]))
+                    complete(slot, st, int(host_tok[i]))
 
-            # 3) decode chunk + retirement (same accounting as _serve_loop)
-            if not active:
-                continue
-            stats["peak_active"] = max(stats["peak_active"], len(active))
-            if sampled:
-                cur, cache, done, sstate, e_d, n_d = self._sample_chunk_fn(
-                    chunk, use_tt, dk)(self.params, cache, cur, done,
-                                       sstate)
-                host_e = np.asarray(e_d)             # one fetch per chunk
-                host_n = np.asarray(n_d)
-            else:
-                cur, cache, done, toks_d = self._chunk_fn(chunk)(
-                    self.params, cache, cur, done)
-                host_e = np.asarray(toks_d)[:, :, None]
-                host_n = np.ones(host_e.shape[:2], np.int64)
-            if dk:
-                _bump_spec_stats(stats, host_n, active)
-            retired = []
-            for slot, ridx in active.items():
-                left[ridx], fin = _consume_slot(host_e, host_n, slot,
-                                                outs[ridx], left[ridx],
-                                                self.eos_id)
-                if fin:
-                    retired.append(slot)
-            for slot in retired:
-                del active[slot]
-                free.append(slot)
-                done = done.at[slot].set(True)
-                cache = backend.retire(cache, slot)
-                if not paged:
-                    # park the freed stripe's write cursor back at smax
-                    # (see the loop docstring)
-                    cache = dict(cache, length=cache["length"].at[slot]
-                                 .set(smax))
-        stats["ttft_s"] = [ttft.get(i, float("nan"))
-                           for i in range(len(prompts))]
-        self.serve_stats = stats
-        return outs
+        it = 0
+        while queue or pending or call.active:
+            with StepTraceAnnotation("serve.iter", step_num=it):
+                it += 1
+                # 1) slot assignment: reservation only, arrival order; a
+                # deferred reservation (paged pool exhausted) is skipped, not
+                # head-of-line blocking
+                with TraceAnnotation("serve.host.assign"):
+                    skipped: List[int] = []
+                    while queue and call.free:
+                        ridx = queue.popleft()
+                        if budgets[ridx] <= 0:
+                            continue
+                        grant = backend.reserve(ridx, len(prompts[ridx]),
+                                                budgets[ridx] + dmargin)
+                        if grant is None:
+                            skipped.append(ridx)
+                            stats["deferred_admissions"] += 1
+                            continue
+                        pending[call.free.pop()] = [ridx, 0, grant]
+                        call.stamp(call.assign_s, ridx)
+                    queue.extendleft(reversed(skipped))
+                if not pending and not call.active:
+                    if queue:  # deferred with nothing left to retire
+                        backend.starved(queue[0], blens, budgets)
+                    continue
+
+                # 2) prefill: ≤ prefill_chunk prompt tokens this iteration
+                # (always ≥ one chunk of progress when anything is pending) —
+                # continuations first, then the packed first-chunk call
+                budget = prefill_chunk if prefill_chunk > 0 else float("inf")
+                spent = 0
+                for slot, st in list(pending.items()):
+                    if st[1] == 0:
+                        continue
+                    if spent >= budget:
+                        break
+                    spent += run_continue(slot, st)
+                items = []
+                for slot, st in list(pending.items()):
+                    if st[1] != 0:
+                        continue
+                    length = len(prompts[st[0]])
+                    c = (min(length, prefill_chunk) if prefill_chunk > 0
+                         else length)
+                    if (spent > 0 or items) and spent + c > budget:
+                        break
+                    items.append((slot, st, c))
+                    spent += c
+                if items:
+                    run_packed(items)
+
+                # 3) decode chunk + retirement
+                if call.active:
+                    self._decode_chunk(call, chunk, backend, use_tt, dk,
+                                       park=None if paged else smax)
+        self.serve_stats = call.finish()
+        return call.outs
 
 
 # ---------------------------------------------------------------------------
