@@ -142,9 +142,9 @@ def _residency(cfg, packed, proj) -> Dict:
 
 def _early_exit(eng, steps: int = 32) -> Dict:
     """All-done early exit inside the decode chunk (DESIGN.md §15): once
-    every row's ``done`` flag is set mid-chunk, the remaining scan
-    iterations take the `lax.cond` skip branch instead of the
-    whole-model step. Measured directly on the jitted chunk: the same
+    every row's ``done`` flag is set mid-chunk, the chunk's step loop
+    stops instead of running the remaining whole-model steps. Measured
+    directly on the jitted chunk: the same
     chunk timed with all rows live vs all rows already done — the gap is
     what a request that finishes early in a long chunk no longer pays."""
     from repro.models import registry
@@ -163,12 +163,12 @@ def _early_exit(eng, steps: int = 32) -> Dict:
         jax.block_until_ready(out[3])
         return time.perf_counter() - t0
 
-    once(False), once(True)                      # compile both branches
+    once(False), once(True)                      # compile, warm up
     t_live = min(once(False) for _ in range(3))
     t_done = min(once(True) for _ in range(3))
     assert t_done < t_live, (
         f"all-done chunk ({t_done:.4f}s) not faster than a live one "
-        f"({t_live:.4f}s) — the early-exit cond is not short-circuiting")
+        f"({t_live:.4f}s) — the early exit is not short-circuiting")
     return {"chunk_steps": steps,
             "live_chunk_s": round(t_live, 5),
             "all_done_chunk_s": round(t_done, 5),

@@ -7,10 +7,11 @@ ignores the KV grid dim (revisited once per KV block), with running
 (m, l, acc) scratch guarded by first/last-visit ``pl.when``. The score
 tile ``[bq, bkv]`` is a kernel-body intermediate, not a BlockSpec, so
 it rides in ``extra_vmem_bytes`` — the same term `_footprint` charges.
-The per-row start / q_offset scalars and the decode block table are
-scalar-prefetched (SMEM), not VMEM blocks; the paged decode contract
-closes its KV index maps over a concrete identity block table,
-mirroring that indirection.
+The per-row start / q_offset scalars and the decode block table and
+layer index are scalar-prefetched (SMEM), not VMEM blocks; the paged
+decode contract closes its KV index maps over a concrete identity block
+table and the last layer of a stacked pool, mirroring that
+indirection.
 """
 from __future__ import annotations
 
@@ -98,29 +99,31 @@ def _packed(hq: int, hkv: int, t: int, d: int, itemsize: int = 4
 
 
 def _paged(b: int, hkv: int, g: int, d: int, page: int, n_log: int,
-           itemsize: int = 4) -> KernelContract:
+           n_layers: int = 2, itemsize: int = 4) -> KernelContract:
     gp = round_up(g, SUBLANE)
     n_phys = b * n_log                      # identity table's pool size
     tab = (np.arange(b, dtype=np.int32)[:, None] * n_log
            + np.arange(n_log, dtype=np.int32)[None, :])
+    layer = n_layers - 1                    # the stack's far edge
 
     def kv_map(bb, j):
-        return (int(tab[bb, j]), 0, 0, 0)
+        return (layer, int(tab[bb, j]), 0, 0, 0)
 
     ok = (paged_decode_ok(page, hkv, d, itemsize)
           and skinny_ok(g, d, itemsize))
     return KernelContract(
-        name=f"attn_decode[b{b} h{hkv} g{g} d{d} p{page}x{n_log}]",
+        name=f"attn_decode[b{b} h{hkv} g{g} d{d} p{page}x{n_log} "
+             f"l{n_layers}]",
         route="attn_decode_flash", domain="attn_decode",
         grid=(b, n_log),
         dimension_semantics=("parallel", "arbitrary"),
         inputs=(
             BlockDecl("q", (1, hkv, gp, d), lambda bb, j: (bb, 0, 0, 0),
                       (b, hkv, gp, d), itemsize),
-            BlockDecl("k_pages", (1, page, hkv, d), kv_map,
-                      (n_phys, page, hkv, d), itemsize),
-            BlockDecl("v_pages", (1, page, hkv, d), kv_map,
-                      (n_phys, page, hkv, d), itemsize),
+            BlockDecl("k_pages", (1, 1, page, hkv, d), kv_map,
+                      (n_layers, n_phys, page, hkv, d), itemsize),
+            BlockDecl("v_pages", (1, 1, page, hkv, d), kv_map,
+                      (n_layers, n_phys, page, hkv, d), itemsize),
         ),
         outputs=(BlockDecl("out", (1, hkv, gp, d),
                            lambda bb, j: (bb, 0, 0, 0),
@@ -132,8 +135,8 @@ def _paged(b: int, hkv: int, g: int, d: int, page: int, n_log: int,
         vmem_budget=KERNEL_VMEM_BUDGET,
         extra_vmem_bytes=gp * page * 4,     # one head's score tile
         admitted=ok, vmem_reject=not ok,
-        notes="KV index maps close over an identity block table "
-              "(scalar-prefetch indirection)")
+        notes="KV index maps close over an identity block table and a "
+              "layer index (scalar-prefetch indirection)")
 
 
 def contracts() -> List[KernelContract]:

@@ -21,9 +21,13 @@ score→softmax→context chain on-chip:
   (M ≤ 32 — the skinny regime, `kernels.common.skinny_ok`) stay resident
   while KV streams through the K loop in fixed-size **pages** gathered via
   a per-row **block table** (scalar-prefetched, so the table lookup drives
-  the DMA index map — the physical page layout in HBM is arbitrary). A
-  contiguous cache is the special case of an identity block table, which
-  is how `decode_attention_apply` reuses this kernel (DESIGN.md §10).
+  the DMA index map — the physical page layout in HBM is arbitrary). The
+  pool is the serving cache's whole [L, P, page, Hkv, D] stack and a
+  scalar-prefetched layer index picks the layer, so a layer loop hands
+  the kernel the pool it carries instead of a sliced-out layer. A
+  contiguous cache is the special case of an identity block table over a
+  one-layer stack, which is how `decode_attention_apply` reuses this
+  kernel (DESIGN.md §10).
 
 Numerics match the chunked XLA path in `models.attention`: scores
 accumulate in f32 on the MXU (operands stay in storage dtype), the
@@ -34,9 +38,10 @@ final normalization divides by ``max(l, 1e-30)``.
 Shape contract (pad at the ops layer):
     prefill: q [B, Hq, T, D], k/v [B, Hkv, S, D], start [B, 1] int32,
              T % block_q == 0, S % block_kv == 0, Hq % Hkv == 0
-    decode:  q [B, Hkv, G, D], k/v pages [P, page, Hkv, D] (one grid step
-             reads one page of every head),
-             block table [B, n_log] int32, lengths/start [B] int32
+    decode:  q [B, Hkv, G, D], k/v pools [L, P, page, Hkv, D] stacked over
+             layers (one grid step reads one page of every head of layer
+             ``layer``), block table [B, n_log] int32, lengths/start [B]
+             int32, layer [1] int32
 """
 from __future__ import annotations
 
@@ -338,8 +343,9 @@ def flash_prefill_packed_pallas(
 # decode (paged KV)
 # ---------------------------------------------------------------------------
 
-def _paged_decode_kernel(tab_ref, len_ref, start_ref, q_ref, k_ref, v_ref,
-                         o_ref, m_ref, l_ref, acc_ref, *, n_log: int,
+def _paged_decode_kernel(tab_ref, len_ref, start_ref, layer_ref, q_ref,
+                         k_ref, v_ref, o_ref, m_ref, l_ref, acc_ref, *,
+                         n_log: int,
                          page: int, hkv: int, sm_scale: float, window: int,
                          softcap: float, out_dtype):
     bb = pl.program_id(0)
@@ -371,13 +377,13 @@ def _paged_decode_kernel(tab_ref, len_ref, start_ref, q_ref, k_ref, v_ref,
         # strided read of it with its own online-softmax state
         for h in range(hkv):
             q = q_ref[0, h]                             # [G, D]
-            k = k_ref[0, :, h, :]                       # [page, D]
+            k = k_ref[0, 0, :, h, :]                    # [page, D]
             s = jax.lax.dot_general(
                 q, k, dimension_numbers=(((1,), (1,)), ((), ())),
                 preferred_element_type=jnp.float32) * sm_scale
             s = jnp.where(mask, _softcap(s, softcap), NEG_INF)
-            _online_update(s, v_ref[0, :, h, :], m_ref.at[h], l_ref.at[h],
-                           acc_ref.at[h])
+            _online_update(s, v_ref[0, 0, :, h, :], m_ref.at[h],
+                           l_ref.at[h], acc_ref.at[h])
 
     @pl.when(j == n_log - 1)
     def _store():
@@ -388,25 +394,29 @@ def _paged_decode_kernel(tab_ref, len_ref, start_ref, q_ref, k_ref, v_ref,
 
 def paged_decode_pallas(
     q: jax.Array,                 # [B, Hkv, G, D] — one token, grouped heads
-    k_pages: jax.Array,           # [P, page, Hkv, D] physical page pool
-    v_pages: jax.Array,           # [P, page, Hkv, D]
+    k_pages: jax.Array,           # [L, P, page, Hkv, D] stacked page pools
+    v_pages: jax.Array,           # [L, P, page, Hkv, D]
     block_table: jax.Array,       # [B, n_log] int32: logical → physical page
     lengths: jax.Array,           # [B] int32 — absolute slot of the new token
     start: jax.Array,             # [B] int32 — first real (non-pad) slot
+    layer: jax.Array,             # [1] int32 — which layer's pool to read
     *,
     sm_scale: float,
     window: int = 0,
     softcap: float = 0.0,
     interpret: bool = False,
 ) -> jax.Array:
-    """One-token decode attention over a paged KV cache. The block table is
-    scalar-prefetched so it drives the KV page DMA index map: logical page
-    ``j`` of row ``b`` is fetched from physical page ``block_table[b, j]``,
-    all KV heads at once. Returns o [B, Hkv, G, D] in q.dtype. The new
-    token's K/V must already be scattered into the pool (slot
-    ``lengths[b]``)."""
+    """One-token decode attention over layer ``layer`` of a paged KV pool
+    stacked over layers. The block table and the layer index are
+    scalar-prefetched so they drive the KV page DMA index map: logical
+    page ``j`` of row ``b`` is fetched from page ``block_table[b, j]`` of
+    layer ``layer``, all KV heads at once, and no other page of the pool is
+    read. Returns o [B, Hkv, G, D] in q.dtype. The new token's K/V must
+    already be scattered into the pool (slot ``lengths[b]`` of that
+    layer). The operands open with the block table, lengths and starts,
+    which is how a trace reader finds this op."""
     b, hkv, g, d = q.shape
-    _, page, hkv2, _ = k_pages.shape
+    _, _, page, hkv2, _ = k_pages.shape
     assert hkv2 == hkv, (k_pages.shape, q.shape)
     n_log = block_table.shape[1]
 
@@ -414,19 +424,21 @@ def paged_decode_pallas(
         _paged_decode_kernel, n_log=n_log, page=page, hkv=hkv,
         sm_scale=sm_scale, window=window, softcap=softcap,
         out_dtype=q.dtype)
+
+    def kv_map(bb, j, tab, ln, st, ly):
+        return (ly[0], tab[bb, j], 0, 0, 0)
+
     grid_spec = pltpu.PrefetchScalarGridSpec(
-        num_scalar_prefetch=3,
+        num_scalar_prefetch=4,
         grid=(b, n_log),
         in_specs=[
             pl.BlockSpec((1, hkv, g, d),
-                         lambda bb, j, tab, ln, st: (bb, 0, 0, 0)),
-            pl.BlockSpec((1, page, hkv, d),
-                         lambda bb, j, tab, ln, st: (tab[bb, j], 0, 0, 0)),
-            pl.BlockSpec((1, page, hkv, d),
-                         lambda bb, j, tab, ln, st: (tab[bb, j], 0, 0, 0)),
+                         lambda bb, j, tab, ln, st, ly: (bb, 0, 0, 0)),
+            pl.BlockSpec((1, 1, page, hkv, d), kv_map),
+            pl.BlockSpec((1, 1, page, hkv, d), kv_map),
         ],
         out_specs=pl.BlockSpec((1, hkv, g, d),
-                               lambda bb, j, tab, ln, st: (bb, 0, 0, 0)),
+                               lambda bb, j, tab, ln, st, ly: (bb, 0, 0, 0)),
         scratch_shapes=[
             pltpu.VMEM((hkv, g, 128), jnp.float32),     # running max m
             pltpu.VMEM((hkv, g, 128), jnp.float32),     # running sum l
@@ -441,4 +453,4 @@ def paged_decode_pallas(
         compiler_params=CompilerParams(
             dimension_semantics=("parallel", "arbitrary")),
         interpret=interpret,
-    )(block_table, lengths, start, q, k_pages, v_pages)
+    )(block_table, lengths, start, layer, q, k_pages, v_pages)

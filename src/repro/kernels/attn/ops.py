@@ -14,16 +14,17 @@ wrappers).
 the chunked XLA path when even the smallest legal block pair would not
 fit — the kernel never partially materializes.
 
-`paged_decode_attention` wraps the block-table decode kernel; a contiguous
-cache is served by the same wrapper through an identity block table
-(`identity_block_table`), which is what makes paged-vs-contiguous decode
-bit-identical: one kernel, one page-visit order, only the physical page
-layout differs.
+`paged_decode_attention` wraps the block-table decode kernel, which reads
+one layer of a pool stacked over layers; a contiguous cache is served by
+the same wrapper through an identity block table
+(`identity_block_table`) over a one-layer stack, which is what makes
+paged-vs-contiguous decode bit-identical: one kernel, one page-visit
+order, only the physical page layout differs.
 """
 from __future__ import annotations
 
 import math
-from typing import Optional, Tuple
+from typing import Optional, Tuple, Union
 
 import jax
 import jax.numpy as jnp
@@ -281,12 +282,13 @@ def identity_block_table(b: int, n_log: int) -> jax.Array:
 
 def paged_decode_attention(
     q: jax.Array,                 # [B, Hkv, G, D]
-    k_pages: jax.Array,           # [P, page, Hkv, D]
-    v_pages: jax.Array,           # [P, page, Hkv, D]
+    k_pages: jax.Array,           # [L, P, page, Hkv, D] or [P, page, Hkv, D]
+    v_pages: jax.Array,           # same shape as k_pages
     block_table: jax.Array,       # [B, n_log] int32
     lengths: jax.Array,           # [B] int32
     start: Optional[jax.Array] = None,    # [B] int32
     *,
+    layer: Union[int, jax.Array] = 0,
     sm_scale: Optional[float] = None,
     window: int = 0,
     softcap: float = 0.0,
@@ -294,9 +296,15 @@ def paged_decode_attention(
     use_kernel: bool = True,
 ) -> jax.Array:
     """One-token decode over a paged (or identity-table contiguous) KV
-    cache. Query rows (the GQA group, G ≤ 32 — `skinny_ok` gates upstream)
-    pad to the sublane quantum; pad rows are sliced off."""
+    cache. The pools are either stacked over layers, [L, P, page, Hkv, D],
+    with ``layer`` (a traced scalar is fine) naming the layer to attend,
+    or one layer's [P, page, Hkv, D] pool, which is the stacked form with
+    L = 1 and layer 0 — one kernel serves both. Query rows (the GQA group,
+    G ≤ 32 — `skinny_ok` gates upstream) pad to the sublane quantum; pad
+    rows are sliced off."""
     b, hkv, g, d = q.shape
+    if k_pages.ndim == 4:
+        k_pages, v_pages = k_pages[None], v_pages[None]
     if sm_scale is None:
         sm_scale = 1.0 / math.sqrt(d)
     if interpret is None:
@@ -305,14 +313,16 @@ def paged_decode_attention(
         start = jnp.zeros((b,), jnp.int32)
     lengths = jnp.asarray(lengths, jnp.int32)
     start = jnp.asarray(start, jnp.int32)
+    layer = jnp.asarray(layer, jnp.int32).reshape(1)
     if not use_kernel:
-        return paged_decode_ref(q, k_pages, v_pages, block_table, lengths,
-                                start, sm_scale=sm_scale, window=window,
+        return paged_decode_ref(q, k_pages[layer[0]], v_pages[layer[0]],
+                                block_table, lengths, start,
+                                sm_scale=sm_scale, window=window,
                                 softcap=softcap)
     gp = round_up(g, SUBLANE)
     qp = q if gp == g else jnp.pad(q, ((0, 0), (0, 0), (0, gp - g), (0, 0)))
     o = paged_decode_pallas(qp, k_pages, v_pages,
                             jnp.asarray(block_table, jnp.int32), lengths,
-                            start, sm_scale=sm_scale, window=window,
+                            start, layer, sm_scale=sm_scale, window=window,
                             softcap=softcap, interpret=interpret)
     return o[:, :, :g]

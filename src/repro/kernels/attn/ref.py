@@ -103,11 +103,16 @@ def packed_prefill_ref(
     return o.astype(q.dtype)
 
 
-def gather_pages(pages: jax.Array, block_table: jax.Array) -> jax.Array:
-    """[P, page, H, D] pool + [B, n_log] table → contiguous [B, S, H, D]."""
+def gather_pages(pages: jax.Array, block_table: jax.Array,
+                 layer: Optional[jax.Array] = None) -> jax.Array:
+    """[P, page, H, D] pool + [B, n_log] table → contiguous [B, S, H, D].
+    With ``layer``, ``pages`` is a pool stacked over layers,
+    [L, P, page, H, D], and the rows come from that layer in one gather —
+    the layer's pool is never sliced out first."""
     b, n_log = block_table.shape
-    _, page, h, d = pages.shape
-    gathered = pages[block_table]                       # [B, n_log, page, H, D]
+    page, h, d = pages.shape[-3:]
+    gathered = (pages[block_table] if layer is None
+                else pages[layer, block_table])        # [B, n_log, page, H, D]
     return gathered.reshape(b, n_log * page, h, d)
 
 

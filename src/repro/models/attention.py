@@ -11,7 +11,8 @@ route is active (single device, float operands, VMEM guard passes); the
 each q-chunk attends to; **naive** is the quadratic oracle. Decode routes
 through the paged flash kernel (a contiguous cache is an identity block
 table); `paged_decode_attention_apply` is the true paged-pool variant the
-continuous-batching engine scans over. KV heads are never materialized at
+continuous-batching engine runs, on one layer of the stacked pool its
+layer loop carries. KV heads are never materialized at
 Hq width (GQA grouping stays factored) on any path.
 """
 from __future__ import annotations
@@ -532,21 +533,25 @@ def verify_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
 def paged_verify_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
                                  k_pages: jax.Array, v_pages: jax.Array,
                                  block_table: jax.Array, lengths: jax.Array,
+                                 layer: jax.Array,
                                  start: Optional[jax.Array] = None,
                                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """`verify_attention_apply` against the paged KV pool (DESIGN.md
-    §10/§15): the T candidate K/V scatter through the block table to
-    their owning physical pages, then the row's logical cache is
-    gathered back for the same naive masked attention — identical key
-    order and identical f32 arithmetic as the contiguous twin, so paged
-    and contiguous speculative serving stay bit-identical. Rows whose
-    table points at the reserved dummy page (retired slots still
-    stepping) write there harmlessly; logical page indices clamp so
-    overshoot never runs off the table."""
+    """`verify_attention_apply` against layer ``layer`` of the paged KV
+    pools (DESIGN.md §10/§15): x [B, T, d]; k_pages/v_pages
+    [L, P, page, Hkv, D], the whole stack the layer loop carries. The T
+    candidate K/V scatter through the block table to their owning pages of
+    that layer, in place, then the row's logical cache is gathered back
+    from that layer (one gather) for the same naive masked attention —
+    identical key order and identical f32 arithmetic as the contiguous
+    twin, so paged and contiguous speculative serving stay bit-identical.
+    Rows whose table points at the reserved dummy page (retired slots
+    still stepping) write there harmlessly; logical page indices clamp so
+    overshoot never runs off the table. Returns (y, k_pages, v_pages),
+    the stacks with this layer's writes."""
     from repro.kernels.attn.ref import gather_pages
     b, t, _ = x.shape
     hq, hd = cfg.num_heads, cfg.resolved_head_dim
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     n_log = block_table.shape[1]
     st = jnp.zeros_like(lengths) if start is None else start
     qpos = (lengths - st)[:, None] + jnp.arange(t)[None, :]   # [B,T] logical
@@ -556,27 +561,31 @@ def paged_verify_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
     logp = jnp.clip(slots // page, 0, n_log - 1)
     phys = jnp.take_along_axis(block_table, logp, axis=1)     # [B,T]
     off = slots % page
-    new_kp = k_pages.at[phys, off].set(k.astype(k_pages.dtype))
-    new_vp = v_pages.at[phys, off].set(v.astype(v_pages.dtype))
+    k_pages = k_pages.at[layer, phys, off].set(k.astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, phys, off].set(v.astype(v_pages.dtype))
 
-    krow = gather_pages(new_kp, block_table)                  # [B, S, Hkv, D]
-    vrow = gather_pages(new_vp, block_table)
+    krow = gather_pages(k_pages, block_table, layer)          # [B, S, Hkv, D]
+    vrow = gather_pages(v_pages, block_table, layer)
     kpos = jnp.arange(n_log * page)[None, :] - st[:, None]
     o = _naive_attention(q, krow, vrow, qpos, kpos, cfg)
     return (_o_proj(p["o_proj"], o.reshape(b, t, hq * hd), cfg),
-            new_kp, new_vp)
+            k_pages, v_pages)
 
 
 def paged_decode_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
                                  k_pages: jax.Array, v_pages: jax.Array,
                                  block_table: jax.Array, lengths: jax.Array,
+                                 layer: jax.Array,
                                  window_override: Optional[int] = None,
                                  start: Optional[jax.Array] = None,
                                  ) -> Tuple[jax.Array, jax.Array, jax.Array]:
-    """One-token decode against a paged KV pool (DESIGN.md §10): x [B, 1, d];
-    k_pages/v_pages [P, page, Hkv, D]; block_table [B, n_log] maps each
-    row's logical pages to physical pool pages. Returns
-    (y, new_k_pages, new_v_pages).
+    """One-token decode against layer ``layer`` of the paged KV pools
+    (DESIGN.md §10): x [B, 1, d]; k_pages/v_pages [L, P, page, Hkv, D], the
+    whole stack the layer loop carries; block_table [B, n_log] maps each
+    row's logical pages to physical pool pages. The new token's K/V are
+    written at ``[layer, page, offset]`` in place and the decode kernel
+    reads that layer's pages straight out of the stack. Returns
+    (y, k_pages, v_pages), the stacks with this layer's writes.
 
     Same per-row contract as `decode_attention_apply`: ``lengths`` is the
     absolute cache slot of the new token, ``start`` the first real slot of
@@ -589,7 +598,7 @@ def paged_decode_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
     b = x.shape[0]
     hq, hkv, hd = cfg.num_heads, cfg.num_kv_heads, cfg.resolved_head_dim
     g = hq // hkv
-    page = k_pages.shape[1]
+    page = k_pages.shape[2]
     n_log = block_table.shape[1]
     rope_pos = lengths if start is None else lengths - start
     q, k, v = _project_qkv(p, cfg, x, rope_pos[:, None])
@@ -597,8 +606,8 @@ def paged_decode_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
     logp = jnp.clip(lengths // page, 0, n_log - 1)
     phys = jnp.take_along_axis(block_table, logp[:, None], axis=1)[:, 0]
     off = lengths % page
-    new_kp = k_pages.at[phys, off].set(k[:, 0].astype(k_pages.dtype))
-    new_vp = v_pages.at[phys, off].set(v[:, 0].astype(v_pages.dtype))
+    k_pages = k_pages.at[layer, phys, off].set(k[:, 0].astype(k_pages.dtype))
+    v_pages = v_pages.at[layer, phys, off].set(v[:, 0].astype(v_pages.dtype))
 
     # the pool has no other decode path: the engine offers paged serving
     # only where the registry picks the kernel, which this asserts
@@ -613,7 +622,7 @@ def paged_decode_attention_apply(p: Dict, cfg: ModelConfig, x: jax.Array,
     window = (cfg.sliding_window if window_override is None
               else window_override)
     o = paged_decode_attention(
-        q.reshape(b, hkv, g, hd), new_kp, new_vp, block_table, lengths,
-        start, window=window, softcap=cfg.attn_logit_softcap)
+        q.reshape(b, hkv, g, hd), k_pages, v_pages, block_table, lengths,
+        start, layer=layer, window=window, softcap=cfg.attn_logit_softcap)
     o = o.reshape(b, 1, hq * hd).astype(x.dtype)
-    return _o_proj(p["o_proj"], o, cfg), new_kp, new_vp
+    return _o_proj(p["o_proj"], o, cfg), k_pages, v_pages

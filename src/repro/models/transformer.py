@@ -290,16 +290,34 @@ def init_cache(cfg: ModelConfig, batch: int, max_len: int) -> Dict:
     }
 
 
-def _cached_layer_body(cfg: ModelConfig, attn_call):
-    """One cached-step layer body (decode and speculative verify; the KV
-    layout — contiguous vs paged, DESIGN.md §10 — and the step kind only
-    change the attention call, so all four paths share this block and
-    cannot drift)."""
-    def body(x, xs):
-        lp, ck, cv = xs
+def _scan_cached_layers(params: Dict, cfg: ModelConfig, x: jax.Array,
+                        cache: Dict, attn_call) -> Tuple[jax.Array, Dict]:
+    """Run the layer stack of a cached step (decode, speculative verify and
+    the three prefills) and apply the final norm. Every such step shares
+    this block — norm, attention, residual, norm, MLP or MoE, residual —
+    and differs only in ``attn_call(lp, h, k, v, l) -> (y, k, v)``, so the
+    paths cannot drift.
+
+    The KV layout (DESIGN.md §10) decides what ``k``/``v`` are:
+
+    * contiguous ``k``/``v`` [L, B, S, Hkv, D] scan with the layers; each
+      layer gets and returns its own [B, S, Hkv, D] slice (``l`` is
+      None);
+    * paged ``k_pages``/``v_pages`` [L, P, page, Hkv, D] ride whole in
+      the loop's carry. Each layer gets both stacks and its index ``l``,
+      writes its new rows at ``[l, page, offset]`` in place, and hands the
+      decode kernel the whole stack plus ``l``: no step slices a layer's
+      pool out, writes it back or copies it. A params stack shorter than
+      the pool (the speculative draft) runs the pool's first layers.
+
+    Returns (hidden, cache with the new K/V)."""
+    paged = "k_pages" in cache
+    kk, vv = ("k_pages", "v_pages") if paged else ("k", "v")
+
+    def block(lp, l, x, k, v):
         lp = _unpack_layer(lp, cfg)
         h = norm_apply(cfg.norm, lp["ln_attn"], x)
-        y, nk, nv = attn_call(lp, h, ck, cv)
+        y, k, v = attn_call(lp, h, k, v, l)
         x = x + y
         h = norm_apply(cfg.norm, lp["ln_mlp"], x)
         if cfg.family == "moe_lm":
@@ -307,8 +325,26 @@ def _cached_layer_body(cfg: ModelConfig, attn_call):
             x = x + z
         else:
             x = x + mlp_apply(lp["mlp"], cfg, h)
-        return x, (nk, nv)
-    return body
+        return x, k, v
+
+    if paged:
+        def step(carry, xs):
+            return block(*xs, *carry), None
+
+        n_layers = jax.tree_util.tree_leaves(params["layers"])[0].shape[0]
+        (x, k, v), _ = jax.lax.scan(
+            step, (x, cache[kk], cache[vv]),
+            (params["layers"], jnp.arange(n_layers, dtype=jnp.int32)))
+    else:
+        def step(x, xs):
+            lp, k, v = xs
+            x, k, v = block(lp, None, x, k, v)
+            return x, (k, v)
+
+        x, (k, v) = jax.lax.scan(step, x, (params["layers"], cache[kk],
+                                           cache[vv]))
+    x = norm_apply(cfg.norm, params["final_norm"], x)
+    return x, dict(cache, **{kk: k, vv: v})
 
 
 def verify_step(params: Dict, cfg: ModelConfig, tokens: jax.Array,
@@ -333,25 +369,16 @@ def verify_step(params: Dict, cfg: ModelConfig, tokens: jax.Array,
         x = x * (cfg.d_model ** 0.5)
     start = cache.get("start")
     lengths = cache["length"]
-
     if "k_pages" in cache:
         table = cache["block_table"]
-        body = _cached_layer_body(
-            cfg, lambda lp, h, kp, vp: attn.paged_verify_attention_apply(
-                lp["attn"], cfg, h, kp, vp, table, lengths, start=start))
-        x, (nkp, nvp) = jax.lax.scan(
-            body, x, (params["layers"], cache["k_pages"],
-                      cache["v_pages"]))
-        x = norm_apply(cfg.norm, params["final_norm"], x)
-        return x, dict(cache, k_pages=nkp, v_pages=nvp)
-
-    body = _cached_layer_body(
-        cfg, lambda lp, h, ck, cv: attn.verify_attention_apply(
+        return _scan_cached_layers(
+            params, cfg, x, cache,
+            lambda lp, h, kp, vp, l: attn.paged_verify_attention_apply(
+                lp["attn"], cfg, h, kp, vp, table, lengths, l, start=start))
+    return _scan_cached_layers(
+        params, cfg, x, cache,
+        lambda lp, h, ck, cv, l: attn.verify_attention_apply(
             lp["attn"], cfg, h, ck, cv, lengths, start=start))
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache["k"],
-                                         cache["v"]))
-    x = norm_apply(cfg.norm, params["final_norm"], x)
-    return x, dict(cache, k=nk, v=nv)
 
 
 def decode_step(params: Dict, cfg: ModelConfig, tokens: jax.Array,
@@ -390,35 +417,25 @@ def decode_step(params: Dict, cfg: ModelConfig, tokens: jax.Array,
         return _zamba2_decode(params, cfg, x, cache)
 
     start = cache.get("start")
-
-    def make_body(attn_call):
-        return _cached_layer_body(cfg, attn_call)
-
     if "k_pages" in cache:
-        # paged KV cache (DESIGN.md §10): per-layer page pools scan with
-        # the layer stack; the block table / lengths / starts are
-        # row-indexed and shared across layers (one allocation serves all
-        # L pools at the same physical page index)
+        # paged KV cache (DESIGN.md §10): the block table / lengths /
+        # starts are row-indexed and shared across layers (one allocation
+        # serves all L pools at the same physical page index)
         table = cache["block_table"]
-        body = make_body(lambda lp, h, kp, vp: attn.paged_decode_attention_apply(
-            lp["attn"], cfg, h, kp, vp, table, cache["length"], start=start))
-        x, (nkp, nvp) = jax.lax.scan(
-            body, x, (params["layers"], cache["k_pages"], cache["v_pages"]))
-        x = norm_apply(cfg.norm, params["final_norm"], x)
-        return x, {"k_pages": nkp, "v_pages": nvp, "block_table": table,
-                   "length": cache["length"] + 1,
-                   "start": (start if start is not None
-                             else jnp.zeros_like(cache["length"]))}
+        x, new_cache = _scan_cached_layers(
+            params, cfg, x, cache,
+            lambda lp, h, kp, vp, l: attn.paged_decode_attention_apply(
+                lp["attn"], cfg, h, kp, vp, table, cache["length"], l,
+                start=start))
+        return x, dict(new_cache, length=cache["length"] + 1,
+                       start=(start if start is not None
+                              else jnp.zeros_like(cache["length"])))
 
-    body = make_body(lambda lp, h, ck, cv: attn.decode_attention_apply(
-        lp["attn"], cfg, h, ck, cv, cache["length"], start=start))
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache["k"],
-                                         cache["v"]))
-    x = norm_apply(cfg.norm, params["final_norm"], x)
-    new_cache = {"k": nk, "v": nv, "length": cache["length"] + 1}
-    if start is not None:
-        new_cache["start"] = start
-    return x, new_cache
+    x, new_cache = _scan_cached_layers(
+        params, cfg, x, cache,
+        lambda lp, h, ck, cv, l: attn.decode_attention_apply(
+            lp["attn"], cfg, h, ck, cv, cache["length"], start=start))
+    return x, dict(new_cache, length=cache["length"] + 1)
 
 
 def _zamba2_decode(params, cfg: ModelConfig, x, cache):
@@ -510,28 +527,17 @@ def prefill(params: Dict, cfg: ModelConfig, tokens=None, embeds=None,
     if start is not None:
         positions = positions - start[:, None]
 
-    def body(x, xs):
-        lp, ck, cv = xs
-        lp = _unpack_layer(lp, cfg)
-        h = norm_apply(cfg.norm, lp["ln_attn"], x)
+    def attend(lp, h, ck, cv, l):
         q, k, v = attn._project_qkv(lp["attn"], cfg, h, positions)
-        nk = jax.lax.dynamic_update_slice(ck, k, (0, 0, 0, 0))
-        nv = jax.lax.dynamic_update_slice(cv, v, (0, 0, 0, 0))
+        ck = jax.lax.dynamic_update_slice(ck, k, (0, 0, 0, 0))
+        cv = jax.lax.dynamic_update_slice(cv, v, (0, 0, 0, 0))
         y = attn.attention_apply(lp["attn"], cfg, h, positions=positions,
                                  ragged=start is not None, qkv=(q, k, v))
-        x = x + y
-        h = norm_apply(cfg.norm, lp["ln_mlp"], x)
-        if cfg.family == "moe_lm":
-            z, _ = moe_apply(lp["moe"], cfg, h)
-            x = x + z
-        else:
-            x = x + mlp_apply(lp["mlp"], cfg, h)
-        return x, (nk, nv)
+        return y, ck, cv
 
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache["k"],
-                                         cache["v"]))
-    x = norm_apply(cfg.norm, params["final_norm"], x)
-    new_cache = {"k": nk, "v": nv, "length": cache["length"] + s}
+    x, filled = _scan_cached_layers(params, cfg, x, cache, attend)
+    new_cache = {"k": filled["k"], "v": filled["v"],
+                 "length": cache["length"] + s}
     if start is not None:
         new_cache["start"] = start
     return x, new_cache
@@ -551,7 +557,8 @@ def prefill_packed(params: Dict, cfg: ModelConfig, tokens: jax.Array,
                         block-diagonal-causal masking)
       rows/cols [Tp]    KV scatter address per token: (batch row, slot) for
                         a contiguous cache, (physical page, offset) for a
-                        paged pool. Padding rows carry an out-of-range row
+                        paged pool (written at every layer of the stack in
+                        place). Padding rows carry an out-of-range row
                         sentinel and are DROPPED by the scatter — no pad
                         token ever lands in a cache.
 
@@ -561,30 +568,18 @@ def prefill_packed(params: Dict, cfg: ModelConfig, tokens: jax.Array,
     half-prefilled rows invisible to the decode batch."""
     assert cfg.family in ("dense_lm", "moe_lm", "vlm_lm", "audio_lm"), cfg.family
     x = _embed_inputs(params, cfg, tokens)
+    paged = "k_pages" in cache
 
-    def body(x, xs):
-        lp, ck, cv = xs
-        lp = _unpack_layer(lp, cfg)
-        h = norm_apply(cfg.norm, lp["ln_attn"], x)
+    def attend(lp, h, ck, cv, l):
         q, k, v = attn._project_qkv(lp["attn"], cfg, h, positions)
-        nk = ck.at[rows, cols].set(k[0].astype(ck.dtype), mode="drop")
-        nv = cv.at[rows, cols].set(v[0].astype(cv.dtype), mode="drop")
+        at = (l, rows, cols) if paged else (rows, cols)
+        ck = ck.at[at].set(k[0].astype(ck.dtype), mode="drop")
+        cv = cv.at[at].set(v[0].astype(cv.dtype), mode="drop")
         y = attn.packed_attention_apply(lp["attn"], cfg, h, seg_ids,
                                         positions, qkv=(q, k, v))
-        x = x + y
-        h = norm_apply(cfg.norm, lp["ln_mlp"], x)
-        if cfg.family == "moe_lm":
-            z, _ = moe_apply(lp["moe"], cfg, h)
-            x = x + z
-        else:
-            x = x + mlp_apply(lp["mlp"], cfg, h)
-        return x, (nk, nv)
+        return y, ck, cv
 
-    kk, vv = (("k_pages", "v_pages") if "k_pages" in cache else ("k", "v"))
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache[kk],
-                                         cache[vv]))
-    x = norm_apply(cfg.norm, params["final_norm"], x)
-    return x, dict(cache, **{kk: nk, vv: nv})
+    return _scan_cached_layers(params, cfg, x, cache, attend)
 
 
 def prefill_continue(params: Dict, cfg: ModelConfig, tokens: jax.Array,
@@ -607,35 +602,22 @@ def prefill_continue(params: Dict, cfg: ModelConfig, tokens: jax.Array,
     if paged:
         from repro.kernels.attn.ref import gather_pages
 
-    def body(x, xs):
-        lp, ck, cv = xs
-        lp = _unpack_layer(lp, cfg)
-        h = norm_apply(cfg.norm, lp["ln_attn"], x)
+    def attend(lp, h, ck, cv, l):
         q, k, v = attn._project_qkv(lp["attn"], cfg, h, positions)
-        nk = ck.at[rows, cols].set(k[0].astype(ck.dtype), mode="drop")
-        nv = cv.at[rows, cols].set(v[0].astype(cv.dtype), mode="drop")
-        if paged:
-            krow = gather_pages(nk, kv_sel[None])       # [1, S, Hkv, D]
-            vrow = gather_pages(nv, kv_sel[None])
+        at = (l, rows, cols) if paged else (rows, cols)
+        ck = ck.at[at].set(k[0].astype(ck.dtype), mode="drop")
+        cv = cv.at[at].set(v[0].astype(cv.dtype), mode="drop")
+        if paged:       # the row's pages of layer l, in one gather each
+            krow = gather_pages(ck, kv_sel[None], layer=l)  # [1, S, Hkv, D]
+            vrow = gather_pages(cv, kv_sel[None], layer=l)
         else:
-            krow = jax.lax.dynamic_slice_in_dim(nk, kv_sel, 1, axis=0)
-            vrow = jax.lax.dynamic_slice_in_dim(nv, kv_sel, 1, axis=0)
+            krow = jax.lax.dynamic_slice_in_dim(ck, kv_sel, 1, axis=0)
+            vrow = jax.lax.dynamic_slice_in_dim(cv, kv_sel, 1, axis=0)
         y = attn.chunk_attention_apply(lp["attn"], cfg, q, krow, vrow,
                                        offset)
-        x = x + y
-        h = norm_apply(cfg.norm, lp["ln_mlp"], x)
-        if cfg.family == "moe_lm":
-            z, _ = moe_apply(lp["moe"], cfg, h)
-            x = x + z
-        else:
-            x = x + mlp_apply(lp["mlp"], cfg, h)
-        return x, (nk, nv)
+        return y, ck, cv
 
-    kk, vv = (("k_pages", "v_pages") if paged else ("k", "v"))
-    x, (nk, nv) = jax.lax.scan(body, x, (params["layers"], cache[kk],
-                                         cache[vv]))
-    x = norm_apply(cfg.norm, params["final_norm"], x)
-    return x, dict(cache, **{kk: nk, vv: nv})
+    return _scan_cached_layers(params, cfg, x, cache, attend)
 
 
 def _zamba2_prefill(params, cfg: ModelConfig, x: jax.Array, cache: Dict

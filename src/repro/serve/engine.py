@@ -60,8 +60,9 @@ __all__ = ["make_decode_step", "make_prefill_step",
            "make_sample_decode_step", "make_spec_decode_step",
            "make_sample_prefill_step", "make_sample_packed_prefill_step",
            "make_sample_chunk_prefill_step",
-           "make_packed_logits_step",
-           "ServeEngine", "greedy_from_hidden", "tp_serve_reason"]
+           "make_packed_logits_step", "make_decode_chunk",
+           "make_sample_chunk", "ServeEngine", "greedy_from_hidden",
+           "tp_serve_reason"]
 
 # Families whose decode cache is the attention [L, B, S, H, D] K/V layout
 # with per-row lengths — the continuous-batching scheduler scatters per-slot
@@ -300,8 +301,8 @@ def make_spec_decode_step(cfg: ModelConfig, draft_k: int,
 
     One speculative step per call: the TRUNCATED model (first
     ``draft_layers`` of the stacked weights, same embed/head) drafts
-    ``draft_k`` tokens autoregressively against a throwaway copy of the
-    cache's first layers; the FULL model verifies all k+1 positions in
+    ``draft_k`` tokens autoregressively against the cache's first layers;
+    the FULL model verifies all k+1 positions in
     one skinny-M batched forward (`registry.verify_step` — K/V written at
     the absolute slots, ``length`` untouched); the standard
     rejection-sampling rule accepts a prefix and resamples the first
@@ -320,7 +321,6 @@ def make_spec_decode_step(cfg: ModelConfig, draft_k: int,
     assert 0 < nd < cfg.num_layers, (nd, cfg.num_layers)
     dcfg = cfg.replace(num_layers=nd)
     k = draft_k
-    _KV_KEYS = ("k", "v", "k_pages", "v_pages")
 
     def head_logits(h2d, p):
         """[M, d] hidden rows → [M, V] FULL-vocab f32 logits (the accept
@@ -340,12 +340,14 @@ def make_spec_decode_step(cfg: ModelConfig, draft_k: int,
         p = _decompress_non_layer(params, cfg)
         b = tokens.shape[0]
         s = sstate
-        # -- draft: k autoregressive steps of the truncated model over a
-        # throwaway first-nd-layers view of the cache (functional copies
-        # — the real cache is untouched until verify writes it)
+        # -- draft: k autoregressive steps of the truncated model. A
+        # contiguous cache hands it a throwaway first-nd-layers copy; the
+        # paged pools are not sliced: the draft runs layers 0..nd-1 of the
+        # real stacks and writes slots length..length+k-1 there, every one
+        # of which verify rewrites, in every layer, before it reads them
         dparams = dict(p, layers=jax.tree_util.tree_map(
             lambda a: a[:nd], p["layers"]))
-        dcache = {key: (v[:nd] if key in _KV_KEYS else v)
+        dcache = {key: (v[:nd] if key in ("k", "v") else v)
                   for key, v in cache.items()}
         cur = tokens
         d_toks, d_lgs = [], []
@@ -367,6 +369,9 @@ def make_spec_decode_step(cfg: ModelConfig, draft_k: int,
         # [cur, d_0..d_{k-1}]; writes K/V at slots length..length+k in
         # every layer, leaves cache["length"] untouched
         vt = jnp.concatenate([tokens[:, None], draft_tok], axis=1)
+        if "k_pages" in cache:
+            cache = dict(cache, k_pages=dcache["k_pages"],
+                         v_pages=dcache["v_pages"])
         hidden, vcache = registry.verify_step(p, cfg, vt, cache)
         vlg = head_logits(hidden.reshape(b * (k + 1), -1), p)
         vlg = vlg.reshape(b, k + 1, -1)                  # [B, k+1, V]
@@ -463,6 +468,98 @@ def make_sample_chunk_prefill_step(cfg: ModelConfig,
         return (nxt, sampling.record_tokens(state, nxt)), new_cache
 
     return step
+
+
+def _chunk_loop(live, carry, steps: int, repeat):
+    """Up to ``steps`` decode steps as one while loop that stops once every
+    row is done (``carry[2]``, the done mask). ``live(carry) -> (carry,
+    out)`` is one step; each leaf of ``out`` lands in a preallocated
+    [steps, ...] block, and a step the loop skipped holds ``repeat(carry)``
+    of the final carry (the last token again — never consumed: a done
+    row's token loop already broke at its EOS). Returns (carry, outs).
+
+    A while loop and not ``scan(cond(all_done, skip, live))``: a cond
+    whose skip branch passes the carry through makes XLA copy the carry,
+    the whole K/V pool, on every step."""
+    outs = jax.tree_util.tree_map(
+        lambda o: jnp.zeros((steps,) + o.shape, o.dtype), repeat(carry))
+
+    def cond(st):
+        i, carry, _ = st
+        return (i < steps) & ~jnp.all(carry[2])
+
+    def body(st):
+        i, carry, outs = st
+        carry, out = live(carry)
+        return i + 1, carry, jax.tree_util.tree_map(
+            lambda blk, o: blk.at[i].set(o), outs, out)
+
+    n, carry, outs = jax.lax.while_loop(cond, body,
+                                        (jnp.int32(0), carry, outs))
+    ran = jnp.arange(steps) < n
+    outs = jax.tree_util.tree_map(
+        lambda blk, r: jnp.where(ran.reshape((steps,) + (1,) * r.ndim),
+                                 blk, r[None]), outs, repeat(carry))
+    return carry, outs
+
+
+def make_decode_chunk(raw, eos_id: int, steps: int):
+    """Greedy decode chunk: ``chunk(params, cache, cur [B], done [B]) ->
+    (cur, cache, done, tokens [steps, B])``, up to ``steps`` steps of
+    ``raw`` (a `make_decode_step` step) on device — ONE host fetch and ONE
+    all-done sync per chunk instead of per token. Once every row is done
+    the remaining steps are skipped (`_chunk_loop`)."""
+
+    @jax.named_scope("decode_chunk")
+    def chunk(params, cache, cur, done):
+        def live(carry):
+            cur, cache, done = carry
+            nxt, cache = raw(params, cache, cur)
+            return (nxt, cache, done | (nxt == eos_id)), nxt
+
+        (cur, cache, done), toks = _chunk_loop(
+            live, (cur, cache, done), steps, lambda c: c[0])
+        return cur, cache, done, toks
+
+    return chunk
+
+
+def make_sample_chunk(raw, eos_id: int, steps: int, draft_k: int):
+    """Sampled twin of `make_decode_chunk` over ``raw`` (a
+    `make_sample_decode_step` step, or `make_spec_decode_step` when
+    ``draft_k > 0``): ``chunk(params, cache, cur, done, sstate) -> (cur,
+    cache, done, sstate, emit [steps, B, ke], n_emit [steps, B])`` with
+    ``ke = draft_k + 1`` — the host drains a variable number of real
+    tokens per step (`_consume_slot`). Same all-done early exit."""
+    ke, spec = draft_k + 1, draft_k > 0
+
+    @jax.named_scope("decode_chunk")
+    def chunk(params, cache, cur, done, sstate):
+        def live(carry):
+            cur, cache, done, sstate = carry
+            if spec:
+                (emit, nem, sstate), cache = raw(params, cache, cur, sstate)
+                mask = jnp.arange(ke)[None, :] < nem[:, None]
+                done = done | jnp.any((emit == eos_id) & mask, axis=1)
+                cur = jnp.take_along_axis(emit, (nem - 1)[:, None],
+                                          axis=1)[:, 0]
+            else:
+                (cur, sstate), cache = raw(params, cache, cur, sstate)
+                emit = cur[:, None]
+                nem = jnp.ones(cur.shape, jnp.int32)
+                done = done | (cur == eos_id)
+            return (cur, cache, done, sstate), (emit, nem)
+
+        def repeat(carry):
+            cur = carry[0]
+            return (jnp.broadcast_to(cur[:, None], (cur.shape[0], ke)),
+                    jnp.ones(cur.shape, jnp.int32))
+
+        (cur, cache, done, sstate), (emit, nem) = _chunk_loop(
+            live, (cur, cache, done, sstate), steps, repeat)
+        return cur, cache, done, sstate, emit, nem
+
+    return chunk
 
 
 def _consume_slot(host_emit: np.ndarray, host_nem: np.ndarray, slot: int,
@@ -749,44 +846,19 @@ class ServeEngine:
     # -- decode chunks: N steps per host round-trip -----------------------
 
     def _chunk_fn(self, steps: int):
-        """Jitted scan of `steps` decode steps. Carries (cur, cache, done)
-        on device and emits the [steps, B] token block — ONE host fetch
-        and ONE all-done scalar sync per chunk instead of per token.
+        """Jitted `make_decode_chunk` of `steps` decode steps, the cache
+        donated.
 
         Callers always pass the engine's fixed `fetch_chunk` and discard
         surplus tokens host-side: each distinct `steps` compiles its own
-        whole-model scan, and a variable tail size would turn the end of
+        whole-model loop, and a variable tail size would turn the end of
         every request into a mid-serving XLA compile. (Overshoot decode
         steps write per-row clamped cache slots whose tokens are never
         consumed — see generate/serve.)"""
         fn = self._chunk_fns.get(steps)
         if fn is None:
-            raw, eos = self._decode_raw, self.eos_id
-
-            @jax.named_scope("decode_chunk")
-            def chunk(params, cache, cur, done):
-                def live(carry):
-                    cur, cache, done = carry
-                    nxt, cache = raw(params, cache, cur)
-                    done = done | (nxt == eos)
-                    return (nxt, cache, done), nxt
-
-                def skip(carry):
-                    # early exit: once every row is done mid-chunk the
-                    # remaining scan iterations skip the whole-model step
-                    # (the repeated cur is never consumed — done rows'
-                    # token loops already broke at their EOS)
-                    return carry, carry[0]
-
-                def body(carry, _):
-                    return jax.lax.cond(jnp.all(carry[2]), skip, live,
-                                        carry)
-
-                (cur, cache, done), toks = jax.lax.scan(
-                    body, (cur, cache, done), None, length=steps)
-                return cur, cache, done, toks
-
-            fn = jax.jit(chunk, donate_argnums=1)
+            fn = jax.jit(make_decode_chunk(self._decode_raw, self.eos_id,
+                                           steps), donate_argnums=1)
             self._chunk_fns[steps] = fn
         return fn
 
@@ -828,54 +900,14 @@ class ServeEngine:
         return fn
 
     def _sample_chunk_fn(self, steps: int, use_tt: bool, draft_k: int):
-        """Sampled twin of `_chunk_fn`: carries (cur, cache, done, sstate)
-        and emits ``(emit [steps, B, ke], n_emit [steps, B])`` with
-        ``ke = draft_k + 1`` (1 for plain sampling) — the host drains a
-        variable number of real tokens per step (`_consume_slot`). Same
-        all-done early exit as the greedy chunk."""
+        """Jitted `make_sample_chunk`, cached per static knob set; the
+        cache and the sampling state are donated."""
         key = (steps, use_tt, draft_k)
         fn = self._sample_chunks.get(key)
         if fn is None:
-            raw = self._sample_raw(use_tt, draft_k)
-            eos, ke = self.eos_id, draft_k + 1
-            spec = draft_k > 0
-
-            @jax.named_scope("decode_chunk")
-            def chunk(params, cache, cur, done, sstate):
-                def live(carry):
-                    cur, cache, done, sstate = carry
-                    if spec:
-                        (emit, nem, sstate), cache = raw(
-                            params, cache, cur, sstate)
-                        mask = jnp.arange(ke)[None, :] < nem[:, None]
-                        done = done | jnp.any((emit == eos) & mask,
-                                              axis=1)
-                        cur = jnp.take_along_axis(
-                            emit, (nem - 1)[:, None], axis=1)[:, 0]
-                    else:
-                        (cur, sstate), cache = raw(params, cache, cur,
-                                                   sstate)
-                        emit = cur[:, None]
-                        nem = jnp.ones(cur.shape, jnp.int32)
-                        done = done | (cur == eos)
-                    return (cur, cache, done, sstate), (emit, nem)
-
-                def skip(carry):
-                    cur = carry[0]
-                    return carry, (
-                        jnp.broadcast_to(cur[:, None],
-                                         (cur.shape[0], ke)),
-                        jnp.ones(cur.shape, jnp.int32))
-
-                def body(carry, _):
-                    return jax.lax.cond(jnp.all(carry[2]), skip, live,
-                                        carry)
-
-                (cur, cache, done, sstate), (emit, nem) = jax.lax.scan(
-                    body, (cur, cache, done, sstate), None, length=steps)
-                return cur, cache, done, sstate, emit, nem
-
-            fn = jax.jit(chunk, donate_argnums=(1, 4))
+            fn = jax.jit(make_sample_chunk(self._sample_raw(use_tt, draft_k),
+                                           self.eos_id, steps, draft_k),
+                         donate_argnums=(1, 4))
             self._sample_chunks[key] = fn
         return fn
 

@@ -14,7 +14,7 @@ from repro.kernels.attn.kernel import flash_prefill_pallas
 from repro.kernels.attn.ops import (flash_attention, flash_ok,
                                     identity_block_table,
                                     paged_decode_attention)
-from repro.kernels.attn.ref import flash_prefill_ref
+from repro.kernels.attn.ref import flash_prefill_ref, paged_decode_ref
 from repro.models import attention as attn_mod
 from repro.models import registry
 
@@ -108,6 +108,27 @@ class TestPagedDecodeKernel:
                                      window=window)
         want = paged_decode_attention(q, kp, vp, tab, lengths, start,
                                       window=window, use_kernel=False)
+        np.testing.assert_allclose(np.asarray(got), np.asarray(want),
+                                   rtol=1e-5, atol=1e-5)
+
+    @pytest.mark.parametrize("g", [1, 4], ids=["mha", "gqa"])
+    @pytest.mark.parametrize("layer", [0, 2], ids=["first", "last"])
+    def test_stacked_pool_layer_matches_ref(self, g, layer):
+        """The kernel over pools stacked over L layers, at a traced layer
+        index, equals the gather reference on that layer's pool alone —
+        the first and the last layer, MHA and GQA, one row whose table
+        sits wholly on the dummy page 0."""
+        n_layers, b, hkv, d, pool, page, n_log = 3, 3, 2, 16, 9, 8, 3
+        q = _rand((b, hkv, g, d), 20)
+        kp = _rand((n_layers, pool, page, hkv, d), 21)
+        vp = _rand((n_layers, pool, page, hkv, d), 22)
+        tab = jnp.asarray([[5, 1, 7], [8, 3, 0], [0, 0, 0]], jnp.int32)
+        lengths = jnp.asarray([17, 9, 5], jnp.int32)
+        start = jnp.asarray([2, 0, 0], jnp.int32)
+        got = jax.jit(lambda ly: paged_decode_attention(
+            q, kp, vp, tab, lengths, start, layer=ly))(jnp.int32(layer))
+        want = paged_decode_ref(q, kp[layer], vp[layer], tab, lengths,
+                                start, sm_scale=1.0 / math.sqrt(d))
         np.testing.assert_allclose(np.asarray(got), np.asarray(want),
                                    rtol=1e-5, atol=1e-5)
 

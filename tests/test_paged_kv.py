@@ -11,6 +11,7 @@ from repro.models import registry
 from repro.serve.engine import ServeEngine
 from repro.serve.kv_cache import (DUMMY_PAGE, PageAllocator, init_paged_cache,
                                   pages_needed)
+from repro.serve.sampling import SamplingParams
 
 
 class TestPageAllocator:
@@ -92,6 +93,47 @@ class TestPagedServing:
                             paged=False)
         out_contig = eng_c.serve(PROMPTS, max_new_tokens=BUDGETS)
         assert out_paged == out_contig
+
+    @pytest.mark.parametrize("path", ["early_exit", "sampled",
+                                      "chunk_continuation"])
+    def test_paged_paths_bit_identical_to_contiguous(self, flash_lm, path):
+        """Each step that carries the stacked pool through its layer loop
+        emits the contiguous engine's tokens: a greedy chunk in which every
+        row finishes mid-chunk (the early exit skips the chunk's other
+        steps), a sampled chunk at fixed seeds, and a prompt longer than
+        the prefill chunk (its continuation chunks gather the row's pages
+        from the stack). Speculative decode has its own such test."""
+        cfg, params = flash_lm
+        kw = dict(max_batch=2, fetch_chunk=4)
+        prompts, budgets, call = PROMPTS, BUDGETS, {}
+        if path == "early_exit":
+            # weights under which greedy tokens vary: the fixture's model
+            # repeats each prompt's last token, so no EOS could fall
+            # mid-chunk
+            params = dict(params, layers=jax.tree_util.tree_map(
+                lambda a: 4 * a, params["layers"]), embed=dict(
+                params["embed"], table=params["embed"]["table"] / 100))
+            row = ServeEngine(cfg, params, eos_id=cfg.vocab_size,
+                              paged=False, **kw).serve(
+                PROMPTS[:1], max_new_tokens=[16])[0]
+            # a first occurrence that is not a chunk's last step
+            k = next(k for k in range(2, len(row))
+                     if row[k] not in row[:k] and k % 4)
+            kw["eos_id"] = row[k]
+            prompts, budgets = PROMPTS[:1], [16]
+        elif path == "sampled":
+            call["sampling"] = [SamplingParams(temperature=0.8, seed=7 + i)
+                                for i in range(len(PROMPTS))]
+        else:
+            prompts, budgets = [list(range(3, 40)), PROMPTS[1]], [5, 4]
+            call["prefill_chunk"] = 8
+        out_p = ServeEngine(cfg, params, **kw).serve(
+            prompts, max_new_tokens=budgets, **call)
+        out_c = ServeEngine(cfg, params, paged=False, **kw).serve(
+            prompts, max_new_tokens=budgets, **call)
+        assert out_p == out_c
+        if path == "early_exit":
+            assert out_p == [row[:k + 1]]
 
     def test_page_recycling_under_pool_pressure(self, flash_lm,
                                                 paged_outputs):
